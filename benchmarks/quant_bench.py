@@ -4,10 +4,10 @@ Measures the two compounding serving optimizations of the quantized path
 against the production configuration they upgrade:
 
   * ``compute_dtype="bf16"`` — bf16 STORAGE for the anchor tables (the
-    VMEM-dominant operand) with register-level upconversion, exact f32
-    selection, and coefficient-dtype (f32) accumulation in the fused
-    Pallas kernel; the halved footprint doubles the default query tile
-    per program (``kernels.knn_fuse.default_block_q``).
+    dominant operand) with register-level upconversion, exact f32
+    selection, and coefficient-dtype (f32) accumulation in the Pallas
+    evaluate kernel; the halved bytes double the default query tile
+    (``kernels.knn_fuse.default_block_q``).
   * ``energy_tau`` representer pruning — ``pruning.prune_plan`` compacts
     the per-cell candidate lists to sensors whose coefficient energy
     clears the threshold, shrinking the ``K_max`` gather width that

@@ -1,13 +1,6 @@
 """Aggregate experiments/dryrun/*.json into the EXPERIMENTS.md roofline table.
 
 Usage: PYTHONPATH=src python -m benchmarks.roofline_report [--dir experiments/dryrun] [--mesh pod]
-       PYTHONPATH=src python -m benchmarks.roofline_report --serving
-
-``--serving`` prints the per-program VMEM residency of the fused kNN
-serving kernel (``kernels.knn_fuse``) at f32 vs bf16 anchor storage —
-the static audit behind the quantized path's "halved footprint, doubled
-tile" claim.  All TIMING numbers in this repo remain CPU interpret-mode;
-the byte accounting here is backend-independent.
 """
 
 from __future__ import annotations
@@ -38,64 +31,12 @@ def fmt(x, unit=""):
     return f"{x:.3g}{unit}"
 
 
-def serving_tile_report(n=1000, d=2, d_max=15, k_max=85, n_cells=256):
-    """Per-program VMEM bytes of ``knn_fuse_pallas``, f32 vs bf16 anchors.
-
-    Shapes mirror the kernel's BlockSpecs (one field slot per program;
-    defaults match the BENCH_quant n=1000 configuration after tau=0
-    compaction).  Only the anchor table changes dtype on the quantized
-    path — queries/positions/selection stay f32 (selection-exact) and the
-    coefficients are never downcast.
-    """
-    from repro.kernels.knn_fuse import default_block_q
-
-    r = n + 1  # padded sensor rows (sentinel)
-
-    def operands(anchor_bytes, block_q):
-        return [
-            ("xq tile", block_q * d * 4),
-            ("qcell tile", block_q * 4),
-            ("cells", n_cells * k_max * 4),
-            ("cell_mask", n_cells * k_max * 1),
-            ("alive", r * 1),
-            ("spos", r * d * 4),
-            ("nbr_pos", r * d_max * d * anchor_bytes),
-            ("nbr_mask", r * d_max * 1),
-            ("coef", r * d_max * 4),
-            ("out tile", block_q * 4),
-        ]
-
-    rows = []
-    for label, anchor_bytes, cdt in (("f32", 4, None), ("bf16", 2, "bfloat16")):
-        bq = default_block_q(cdt)
-        ops = operands(anchor_bytes, bq)
-        total = sum(b for _, b in ops)
-        anchors = dict(ops)["nbr_pos"]
-        rows.append((label, bq, anchors, total))
-    print(f"# fused kNN serving kernel, per-program VMEM "
-          f"(n={n}, D={d_max}, K_max={k_max}, C={n_cells})")
-    print("| anchors | block_q | anchor-table bytes | total resident bytes |")
-    print("|---|---|---|---|")
-    for label, bq, anchors, total in rows:
-        print(f"| {label} | {bq} | {fmt(anchors)}B | {fmt(total)}B |")
-    (l0, _, a0, t0), (l1, _, a1, t1) = rows
-    print(f"# {l1}/{l0}: anchor table x{a1 / a0:.2f}, "
-          f"total x{t1 / t0:.2f} (anchors are the dominant geometric "
-          f"operand; coef stays f32 by design)")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="experiments/dryrun")
     ap.add_argument("--mesh", default="pod")
     ap.add_argument("--csv", action="store_true")
-    ap.add_argument("--serving", action="store_true",
-                    help="print the serving-kernel VMEM tile table "
-                         "(f32 vs bf16 anchors) and exit")
     args = ap.parse_args()
-    if args.serving:
-        serving_tile_report()
-        return
 
     recs = load(args.dir, args.mesh)
     key = lambda r: (r["arch"], SHAPE_ORDER.index(r["shape"]))

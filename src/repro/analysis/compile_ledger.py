@@ -77,9 +77,6 @@ def _entries() -> list[LedgerEntry]:
           "repro.kernels.knn_fuse:knn_fuse_pallas", BUCKETS),
         e("serving.matvec",
           "repro.kernels.kernel_matvec:kernel_matvec_pallas", BUCKETS),
-        e("serving.matvec_batched",
-          "repro.kernels.kernel_matvec:kernel_matvec_batched_pallas",
-          BUCKETS),
         e("serving.plan_add", C + "serving:plan_add_sensor", FROZEN),
         e("serving.plan_remove", C + "serving:plan_remove_sensor", FROZEN),
         # --- pruning: tau is a traced operand

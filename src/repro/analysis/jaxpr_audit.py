@@ -54,10 +54,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.4.x exposes the stable jaxpr types here
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from .report import Finding
 

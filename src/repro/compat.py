@@ -1,15 +1,8 @@
-"""Version-compatibility shims over the moving parts of the JAX API.
+"""Mesh and shard_map construction, in one place.
 
-The repo targets the container's jax (0.4.x) while staying forward-compatible
-with newer releases:
-
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)`` only
-    exist in jax >= 0.5; on 0.4.x meshes are built without axis types.
-  * ``jax.shard_map`` graduated from ``jax.experimental.shard_map`` and its
-    replication-check kwarg was renamed ``check_rep`` -> ``check_vma``.
-
-Everything that builds meshes or shard_maps goes through this module so the
-rest of the codebase can be written against one API.
+Every mesh in the repo uses Auto axis types (sharding propagates through
+``jit`` as in the GSPMD model, no explicit-sharding type checks), and
+every ``shard_map`` turns the varying-manual-axes check off by default.
 """
 
 from __future__ import annotations
@@ -18,34 +11,14 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where supported, plain otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check=False):
-    """shard_map across the jax.experimental -> jax.shard_map migration.
-
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old).
-    """
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        # the flag was spelled check_rep before the check_vma rename, and
-        # some intermediate releases promoted shard_map to the top level
-        # while still using the old spelling — try both before dropping it
-        for kw in ("check_vma", "check_rep"):
-            try:
-                return new(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{kw: check})
-            except TypeError:
-                continue
-        return new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    """``jax.shard_map`` with ``check`` as its ``check_vma`` flag."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
+    )

@@ -56,7 +56,7 @@ def fit_krr(
 
 @partial(jax.jit, static_argnames=("kernel",))
 def _predict(kernel: Kernel, anchors, coef, xq) -> jax.Array:
-    return kernel(xq, anchors) @ coef
+    return jnp.matmul(kernel(xq, anchors), coef, precision="highest")
 
 
 def predict(model: KRRModel, xq: jax.Array, *, use_pallas: bool = False) -> jax.Array:

@@ -28,8 +28,8 @@ Serving engines (the query-plan taxonomy; the training-side analogue is
   independently simple oracle; ``"plan"`` and ``"pallas"`` route through
   the static cell-candidate query plans of ``repro.core.serving``
   (``make_serving_plan``), touching one bounded cell neighborhood per
-  query — O(Q*k*D), with ``"pallas"`` fusing the whole select+evaluate
-  step per query tile in VMEM (``repro.kernels.knn_fuse``).
+  query — O(Q*k*D), with ``"pallas"`` running the selection and the
+  evaluation as Pallas kernels (``repro.kernels.knn_fuse``).
 """
 
 from __future__ import annotations
@@ -48,7 +48,9 @@ def _eval_all(kernel, nbr_pos, nbr_mask, coef, xq):
 
     def eval_s(pos_s, mask_s, coef_s):
         k = kernel(xq, pos_s)  # (Q, D)
-        return k @ jnp.where(mask_s, coef_s, 0.0)
+        return jnp.matmul(
+            k, jnp.where(mask_s, coef_s, 0.0), precision="highest"
+        )
 
     return jax.vmap(eval_s)(nbr_pos, nbr_mask, coef)
 

@@ -22,13 +22,16 @@ def pairwise_sq_dists(x1: jax.Array, x2: jax.Array) -> jax.Array:
     """Squared Euclidean distances, shape (n1, n2).
 
     Uses the expanded form so it lowers to two matmuls (MXU-friendly) rather
-    than an (n1, n2, d) broadcast.
+    than an (n1, n2, d) broadcast.  Every matmul in this module asks for
+    ``precision="highest"``: on a TPU the default is one bf16 pass, whose
+    rounding of the cross term is as large as a neighborhood's squared
+    distances; on a CPU the flag changes nothing.
     """
     x1 = jnp.atleast_2d(x1)
     x2 = jnp.atleast_2d(x2)
     sq1 = jnp.sum(x1 * x1, axis=-1)[:, None]
     sq2 = jnp.sum(x2 * x2, axis=-1)[None, :]
-    cross = x1 @ x2.T
+    cross = jnp.matmul(x1, x2.T, precision="highest")
     return jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
 
 
@@ -41,7 +44,7 @@ def linear_kernel(x1: jax.Array, x2: jax.Array, *, bias: float = 1.0) -> jax.Arr
     """
     x1 = jnp.atleast_2d(x1)
     x2 = jnp.atleast_2d(x2)
-    return x1 @ x2.T + bias
+    return jnp.matmul(x1, x2.T, precision="highest") + bias
 
 
 def rbf_kernel(x1: jax.Array, x2: jax.Array, *, gamma: float = 1.0) -> jax.Array:
@@ -59,7 +62,9 @@ def matern32_kernel(x1: jax.Array, x2: jax.Array, *, length: float = 1.0) -> jax
 def poly_kernel(
     x1: jax.Array, x2: jax.Array, *, degree: int = 2, bias: float = 1.0
 ) -> jax.Array:
-    return (jnp.atleast_2d(x1) @ jnp.atleast_2d(x2).T + bias) ** degree
+    x1 = jnp.atleast_2d(x1)
+    x2 = jnp.atleast_2d(x2)
+    return (jnp.matmul(x1, x2.T, precision="highest") + bias) ** degree
 
 
 _REGISTRY: dict[str, Callable[..., jax.Array]] = {

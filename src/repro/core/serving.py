@@ -32,11 +32,12 @@ Engines (``fusion.fuse(rule="knn", engine=...)`` dispatches here):
 
   ``"plan"``    the jnp realization of the plan path (any kernel, any
                 dtype — the reference the Pallas kernel is tested against);
-  ``"pallas"``  the fused VMEM kernel ``repro.kernels.knn_fuse`` (RBF
-                only): candidate gather, distance tile, masked top-k
-                selection network and the k local (D,) contractions all
-                happen per query tile in VMEM — the (n, Q) predictions
-                and (Q, n) distances never exist in HBM;
+  ``"pallas"``  the Pallas kernels of ``repro.kernels.knn_fuse`` (RBF
+                only): a selection kernel (distance tile + masked top-k
+                network per query tile), an XLA gather of the selected
+                representers, and an evaluate kernel for the k local
+                (D,) contractions — the (n, Q) predictions and (Q, n)
+                distances never exist in HBM;
   ``"dense"``   (in ``fusion``) the original all-sensors oracle.
 
 Network lifecycle: the plan's candidate VALUES are device-side data, so
@@ -63,9 +64,9 @@ engines agree bit-for-bit on the selected set except on exact ties between
 equidistant sensors at different indices.
 
 Quantized + sparsified path: ``compute_dtype="bf16"`` stores the anchor
-tables — serving's VMEM-dominant operand, O(B*n*D*d) vs O(n*d) for the
-sensor positions — in bf16, halving the resident footprint so the Pallas
-query tile doubles, with kernel-value arithmetic upconverted to >= f32 in
+tables — serving's dominant operand, O(B*n*D*d) vs O(n*d) for the
+sensor positions — in bf16, halving the bytes the Pallas path gathers
+and reads so its query tile doubles, with kernel-value arithmetic upconverted to >= f32 in
 registers and the representer contraction accumulating in the COEFFICIENT
 dtype (f32/f64 — ``ecoef`` is never downcast).  Selection is EXACT under
 quantization: queries, positions, distances, and top-k keep full
@@ -331,7 +332,7 @@ def _eval_selected(
     """mean over VALID selections of f_{sel[q,j]}(xq[q]): O(Q*k*D).
 
     ``compute_dtype`` rounds the ANCHOR coordinates (the storage dtype of
-    the quantized path's VMEM-dominant table) before evaluating K(x, x_j)
+    the quantized path's dominant table) before evaluating K(x, x_j)
     at >= f32 (the Pallas kernel's register-level upconversion contract);
     queries stay full-precision and the representer contraction and the
     average accumulate in the coefficient dtype regardless.

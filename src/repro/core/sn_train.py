@@ -58,11 +58,12 @@ Engine selection (``colored_sweep(..., engine=...)``):
                           ``(M·D, n_z)`` and applies the update as two dense
                           GEMMs — O(n²) per sweep, kept as the independently
                           simple oracle the plans are tested against;
-  ``"pallas"``            the fused color-step kernel
-                          (repro.kernels.color_step): gather → lane-blocked
-                          forward/back substitution → local (D,D)@(D,) GEMM
-                          → scatter, all in VMEM, blocked over the B·M lane
-                          grid (interpret mode off-TPU).
+  ``"pallas"``            the plan engine with its local solves in the
+                          Pallas kernel ``repro.kernels.color_step``: the
+                          B·M systems sit on the lane axis, forward/back
+                          substitution and the local (D,D)@(D,) GEMM run
+                          per 128-lane tile; the gather and scatter around
+                          it are the plan's (interpret mode off-TPU).
 
 All three produce identical fixed points (plan == onehot bit-for-bit; see
 tests/test_scatter_plan.py).  ``sharded_sweep`` reuses the plans to shrink
@@ -102,7 +103,7 @@ device-side ``alive`` row mask and a ``layout`` (slot ownership, color
 assignments, pristine slot tables); every sweep engine gates on it:
 
   * dead members never update (their scatters degrade to "keep" in all of
-    plan/onehot/pallas — the Pallas kernels grew explicit alive operands);
+    plan/onehot/pallas — pallas shares the plan's gated scatter);
   * dead rows' message slots — and, via the slot-owner map, their absorbed
     arrivals' slots — drop out of every gather;
   * at all-True liveness the gates are identities BIT-FOR-BIT.
@@ -146,7 +147,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import jax.scipy.linalg as jsl
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
 
@@ -507,7 +508,8 @@ def weighted_norm_sq(problem: SNTrainProblem, state: SNTrainState) -> jax.Array:
     """
     z_part = jnp.sum(state.z[..., :-1] ** 2, axis=-1)  # excludes the sentinel
     quad = jnp.einsum(
-        "...sd,...sde,...se->...s", state.coef, problem.gram, state.coef
+        "...sd,...sde,...se->...s", state.coef, problem.gram, state.coef,
+        precision="highest",
     )
     return z_part + jnp.sum(problem.lam_pad * quad, axis=-1)
 
@@ -553,7 +555,7 @@ def _sensor_update(z, coef_s, nbr_idx_s, nbr_mask_s, gram_s, chol_s, lam_s):
     z_nbr = z[nbr_idx_s]  # (D,)
     rhs = jnp.where(nbr_mask_s, z_nbr + lam_s * coef_s, 0.0)
     coef_new = jsl.cho_solve((chol_s, True), rhs)
-    z_new = gram_s @ coef_new  # f_s(x_j) for j in N_s (masked gram)
+    z_new = jnp.matmul(gram_s, coef_new, precision="highest")  # f_s at N_s
     return coef_new, z_new
 
 
@@ -692,9 +694,18 @@ def _tri_solve_spd(chol, rhs):
     return x
 
 
+def _local_solve(chol_m, gram_m, rhs):
+    """XLA local solves: (coef_new, z_new) = ((K_s + lambda_s I)^{-1} rhs,
+    K_s @ coef_new) per lane; ``kernels.color_step.color_solve`` is the
+    Pallas twin."""
+    coef_new = _tri_solve_spd(chol_m, rhs)
+    z_new = jnp.einsum("bmij,bmj->bmi", gram_m, coef_new, precision="highest")
+    return coef_new, z_new
+
+
 def _color_solve(
     nbr_idx, lam_pad, alive_row, alive_slot, nbr_mask, gram, chol, z, coef,
-    members, member_mask,
+    members, member_mask, *, local_solve=_local_solve,
 ):
     """Simultaneous P_{C_s} local solves for one color, all B fields.
 
@@ -705,7 +716,7 @@ def _color_solve(
     every rhs; at all-True liveness the masks are identities and the floats
     are bit-for-bit those of the lifecycle-free engine.
     Returns (idx_m (M, D), coef_new (B, M, D), z_new (B, M, D)); the engines
-    differ only in how they scatter these back.
+    differ only in how they solve (``local_solve``) and scatter these back.
     """
     idx_m = nbr_idx[members]  # (M, D) shared across fields
     live_m = member_mask & alive_row[members]  # (M,) updating members
@@ -722,8 +733,7 @@ def _color_solve(
     b = z.shape[0]
     z_nbr = z[:, idx_m.reshape(-1)].reshape(b, *idx_m.shape)  # (B, M, D)
     rhs = jnp.where(mask_m, z_nbr + lam_m[None, :, None] * coef_m, 0.0)
-    coef_new = _tri_solve_spd(chol_m, rhs)  # (K_s + lambda_s I)^{-1} rhs
-    z_new = jnp.einsum("bmij,bmj->bmi", gram_m, coef_new)  # f_s at N_s
+    coef_new, z_new = local_solve(chol_m, gram_m, rhs)
     return idx_m, coef_new, z_new
 
 
@@ -788,7 +798,7 @@ def _apply_onehot(
     oh = oh * live_f[:, None] * alive_slot.astype(z.dtype)[None, :]
     hit = oh.sum(axis=0)  # (NZ,)
     z = z * (1.0 - hit)[None, :] + jnp.einsum(
-        "kz,bk->bz", oh, z_new.reshape(b, -1)
+        "kz,bk->bz", oh, z_new.reshape(b, -1), precision="highest"
     )
     # One-hot coefficient scatter over member rows (padded members are the
     # sentinel sensor row n whose update is exactly 0).
@@ -796,7 +806,7 @@ def _apply_onehot(
     ohm = ohm * live_m.astype(coef.dtype)[:, None]
     hitm = ohm.sum(axis=0)  # (n+1,)
     coef = coef * (1.0 - hitm)[None, :, None] + jnp.einsum(
-        "mn,bmd->bnd", ohm, coef_new
+        "mn,bmd->bnd", ohm, coef_new, precision="highest"
     )
     return z, coef
 
@@ -828,8 +838,13 @@ def _colored_core(
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     alive_row = problem.alive if alive is None else alive
     alive_slot = plans.alive_slots(alive_row, problem.layout.slot_owner)
+    if engine == "pallas":
+        from repro.kernels.color_step import color_solve as local_solve
+    else:
+        local_solve = _local_solve
     solve = partial(
-        _color_solve, problem.nbr_idx, problem.lam_pad, alive_row, alive_slot
+        _color_solve, problem.nbr_idx, problem.lam_pad, alive_row, alive_slot,
+        local_solve=local_solve,
     )
     # The member tables are problem state (symmetric joins recolor), so a
     # churned problem sweeps its CURRENT classes with zero recompilation.
@@ -839,50 +854,28 @@ def _colored_core(
     )
 
     def make_color_body(deliv_t):
-        if engine == "pallas":
-            from repro.kernels.color_step import color_step_fused
-
-            def color_body(carry, cm):
-                z, coef = carry
-                members, member_mask, _, _ = cm
-                idx_m = problem.nbr_idx[members]
-                live_m = member_mask & alive_row[members]
-                z, coef = color_step_fused(
-                    z, coef, members, idx_m,
-                    nbr_mask[:, members]
-                    & live_m[None, :, None]
-                    & alive_slot[idx_m][None],
-                    gram[:, members], chol[:, members],
-                    problem.lam_pad[members],
-                    alive_row[members],
-                    alive_slot,
-                    None if deliv_t is None else deliv_t[members],
+        def color_body(carry, cm):
+            z, coef = carry
+            members, member_mask, plan_z_c, plan_coef_c = cm
+            live_m = member_mask & alive_row[members]
+            deliv_flat = (
+                None if deliv_t is None else deliv_t[members].reshape(-1)
+            )
+            idx_m, coef_new, z_new = solve(
+                nbr_mask, gram, chol, z, coef, members, member_mask
+            )
+            if engine == "onehot":
+                z, coef = _apply_onehot(
+                    z, coef, z_new, coef_new, idx_m, members,
+                    problem.n_z, problem.n + 1, live_m, alive_slot,
+                    deliv_flat,
                 )
-                return (z, coef), None
-        else:
-
-            def color_body(carry, cm):
-                z, coef = carry
-                members, member_mask, plan_z_c, plan_coef_c = cm
-                live_m = member_mask & alive_row[members]
-                deliv_flat = (
-                    None if deliv_t is None else deliv_t[members].reshape(-1)
+            else:
+                z, coef = _apply_plan(
+                    z, coef, z_new, coef_new, plan_z_c, plan_coef_c,
+                    live_m, alive_slot, deliv_flat,
                 )
-                idx_m, coef_new, z_new = solve(
-                    nbr_mask, gram, chol, z, coef, members, member_mask
-                )
-                if engine == "plan":
-                    z, coef = _apply_plan(
-                        z, coef, z_new, coef_new, plan_z_c, plan_coef_c,
-                        live_m, alive_slot, deliv_flat,
-                    )
-                else:
-                    z, coef = _apply_onehot(
-                        z, coef, z_new, coef_new, idx_m, members,
-                        problem.n_z, problem.n + 1, live_m, alive_slot,
-                        deliv_flat,
-                    )
-                return (z, coef), None
+            return (z, coef), None
 
         return color_body
 
@@ -919,8 +912,8 @@ def colored_sweep(
     single-field results are identical by construction).
 
     engine: "plan" (static scatter plans, the O(n*D) default), "onehot"
-    (dense one-hot GEMM reference, O(n^2)) or "pallas" (fused VMEM color-step
-    kernel).  All three share the local solves and produce identical fixed
+    (dense one-hot GEMM reference, O(n^2)) or "pallas" (the plan engine
+    with its local solves in the Pallas color-step kernel).  All three share the local solves and produce identical fixed
     points; see the module docstring.
 
     delivered: optional (n_sweeps, n+1, D) bool per-sweep link-delivery
@@ -1124,14 +1117,24 @@ def sharded_sweep(
             (z, coef), _ = jax.lax.scan(sweep, (z, coef), delivered)
         return z, coef
 
+    specs = (P(), P(), P(None, axis, None), P(None, axis, None))
     fn = compat.shard_map(
-        device_fn,
-        mesh=mesh,
-        in_specs=(P(), P(), P(None, axis, None), P(None, axis, None)),
-        out_specs=(P(), P()),
+        device_fn, mesh=mesh, in_specs=specs, out_specs=(P(), P())
     )
-    z, coef = jax.jit(fn)(state.z, state.coef, members, mask)
+    z, coef = jax.jit(fn)(
+        *_place(mesh, specs, (state.z, state.coef, members, mask))
+    )
     return SNTrainState(z=z, coef=coef)
+
+
+def _place(mesh, specs, arrays):
+    """Put each operand on the mesh as its spec says, before the jitted
+    shard_map sees it: an operand left on device 0 would make the program
+    run where it sits instead of across the mesh."""
+    return tuple(
+        jax.device_put(a, NamedSharding(mesh, spec))
+        for a, spec in zip(arrays, specs)
+    )
 
 
 def _sharded_sweep_fields(
@@ -1152,13 +1155,13 @@ def _sharded_sweep_fields(
             delivered=delivered,
         )
 
-    spec = P(axis)
+    specs = (P(axis),) * 5
     fn = compat.shard_map(
-        device_fn, mesh=mesh, in_specs=(spec,) * 5, out_specs=(spec, spec)
+        device_fn, mesh=mesh, in_specs=specs, out_specs=specs[:2]
     )
-    z, coef = jax.jit(fn)(
+    z, coef = jax.jit(fn)(*_place(mesh, specs, (
         problem.nbr_mask, problem.gram, problem.chol, state.z, state.coef
-    )
+    )))
     return SNTrainState(z=z, coef=coef)
 
 
@@ -1228,7 +1231,7 @@ def _dynamic_sensor_update(problem, z, coef_s, s, alive_s, alive_row, alive_slot
     z_nbr = z[problem.nbr_idx[s]]
     rhs = jnp.where(mask, z_nbr + lam * coef_prev, 0.0)
     coef_new = jnp.linalg.solve(a, rhs)
-    z_new = gram @ coef_new
+    z_new = jnp.matmul(gram, coef_new, precision="highest")
     return coef_new, z_new, mask
 
 
@@ -1425,7 +1428,7 @@ def _weighted_sensor_update(problem, z, coef_s, s, w_pad, alive_row, alive_slot)
     z_nbr = z[problem.nbr_idx[s]]
     rhs = jnp.where(mask, w_nbr * z_nbr + lam * coef_s, 0.0)
     coef_new = jnp.linalg.solve(a, rhs)
-    z_new = gram @ coef_new
+    z_new = jnp.matmul(gram, coef_new, precision="highest")
     return coef_new, z_new, mask
 
 
@@ -1482,6 +1485,7 @@ def weighted_norm_sq_hetero(
     n = problem.n
     z_part = jnp.sum(jnp.asarray(weights) * state.z[..., :n] ** 2, axis=-1)
     quad = jnp.einsum(
-        "...sd,...sde,...se->...s", state.coef, problem.gram, state.coef
+        "...sd,...sde,...se->...s", state.coef, problem.gram, state.coef,
+        precision="highest",
     )
     return z_part + jnp.sum(problem.lam_pad * quad, axis=-1)

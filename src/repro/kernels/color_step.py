@@ -1,29 +1,26 @@
-"""Fused Pallas color-step kernel for the colored SN-Train engine.
+"""Pallas color-step solve for the colored SN-Train engine.
 
-One color step of the paper's Sec-3.3 parallel SOP sweep, entirely in VMEM:
+One color step of the paper's Sec-3.3 parallel SOP sweep solves one
+(D, D) SPD system per (field, member) lane and evaluates the fresh local
+function at the member's neighborhood points:
 
-  gather   z at the color's (M, D) message-slot ids and the members' previous
-           coefficient rows;
-  solve    (L L^T)^{-1} rhs by lane-blocked forward/back triangular
-           substitution (the same substitution math as
-           ``sn_train._tri_solve_spd``, one lane per member of the block);
-  GEMM     z_new = K_s @ coef_new per lane — a local (D, D) @ (D,) contract;
-  scatter  the freshly solved messages/coefficients back into the full z and
-           coef buffers.  Distance-2 coloring guarantees every touched slot
-           has a unique owner, so the scatter is an exact write (the static
-           scatter plan of sn_train, realized here as an in-VMEM ``.at.set``).
+  solve    (L L^T)^{-1} rhs by forward/back triangular substitution (the
+           same substitution math as ``sn_train._tri_solve_spd``);
+  GEMM     z_new = K_s @ coef_new — a local (D, D) @ (D,) contract.
 
-Grid: (B, M / block_m) with the lane-block axis innermost, so each field's
-(1, NZ) / (1, n+1, D) output blocks stay resident in VMEM while the color's
-lane blocks stream through — the same revisiting-accumulator pattern as
-``kernels.kernel_matvec``.  Different lane blocks of one color touch disjoint
-slots (the coloring again), so reading the output block between lane steps is
-exact.
+Layout: the B * M independent systems sit on the LANE axis — ``chol`` and
+``gram`` are (D, D, L) and ``rhs`` (D, L) with L = B*M padded to the lane
+block — so every substitution step is one (D, BL) row operation across
+128-lane tiles: load row i of L for all lanes, reduce over the sublane
+axis, select the new row in.  The grid runs over lane blocks only, and
+every block's last two dims are (D, BL): D is the whole axis, BL a
+multiple of 128.
 
-dtype follows the inputs (f32 or, under JAX_ENABLE_X64, f64 — the solver is
-dtype-generic).  On non-TPU backends the wrapper runs in interpret mode (the
-repo's validation mode, see ``kernels.ops``); the in-kernel gathers/scatters
-use dynamic indices, which interpret mode executes exactly.
+The gather of the color's messages / coefficients and the scatter of the
+results stay in XLA around the kernel: the plan engine's
+``sn_train._color_solve`` / ``_apply_plan`` (Mosaic on v5e lowers no
+in-kernel vector gather or scatter).  dtype follows the inputs (f32, or
+f64 under JAX_ENABLE_X64 in interpret mode).
 """
 
 from __future__ import annotations
@@ -34,202 +31,104 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ops import auto_interpret, fit_block
 
-def _color_step_kernel(
-    z_ref, coef_ref, mem_ref, idx_ref, mask_ref, gram_ref, chol_ref, lam_ref,
-    alive_ref, alivez_ref, deliv_ref, zout_ref, cout_ref,
-):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        zout_ref[...] = z_ref[...]
-        cout_ref[...] = coef_ref[...]
-
-    z = zout_ref[0, :]  # (NZ,) — prior lane blocks wrote disjoint slots
-    coefv = cout_ref[0]  # (R, D)
-    mem = mem_ref[...]  # (bm,)
-    idx = idx_ref[...]  # (bm, D)
-    mask = mask_ref[0] != 0  # (bm, D)
-    gram = gram_ref[0]  # (bm, D, D)
-    chol = chol_ref[0]  # (bm, D, D)
-    lam = lam_ref[...]  # (bm,)
-    alive = alive_ref[...] != 0  # (bm,) member liveness (network lifecycle)
-    alivez = alivez_ref[...] != 0  # (NZ,) message-slot liveness
-    deliv = deliv_ref[...] != 0  # (bm, D) per-lane link delivery (faults)
-    d = idx.shape[-1]
-
-    # Gather: this block's messages and previous coefficients.
-    z_nbr = z[idx]  # (bm, D)
-    coef_m = coefv[mem]  # (bm, D)
-    rhs = jnp.where(mask, z_nbr + lam[:, None] * coef_m, 0.0)
-
-    # Lane-blocked forward substitution  L y = rhs.
-    def fwd(i, y):
-        yi = (rhs[:, i] - jnp.sum(chol[:, i, :] * y, axis=-1)) / chol[:, i, i]
-        return y.at[:, i].set(yi)
-
-    y = jax.lax.fori_loop(0, d, fwd, jnp.zeros_like(rhs))
-
-    # Lane-blocked back substitution  L^T x = y.
-    def bwd(t, x):
-        i = d - 1 - t
-        xi = (y[:, i] - jnp.sum(chol[:, :, i] * x, axis=-1)) / chol[:, i, i]
-        return x.at[:, i].set(xi)
-
-    coef_new = jax.lax.fori_loop(0, d, bwd, jnp.zeros_like(rhs))
-
-    # Local (D, D) @ (D,) GEMM per lane: f_s at the neighborhood points.
-    z_new = jnp.einsum("mij,mj->mi", gram, coef_new)
-
-    # Scatter (unique owners; padded lanes write zeros to the sentinels).
-    # DEAD members (removed / transiently down sensors) redirect to the
-    # sentinels, and so do lanes whose TARGET slot is dead (a down mote's
-    # own message slot is unreachable) and lanes whose message was DROPPED
-    # by the link (repro.core.faults): slots and coefficient rows KEEP
-    # their values, matching the source/target/delivery gates of the plan
-    # engine.  Coefficients are local compute, so ``deliv`` gates the
-    # message scatter only.
-    n_z = z.shape[0]
-    r = coefv.shape[0]
-    idx_eff = jnp.where(alive[:, None] & alivez[idx] & deliv, idx, n_z - 1)
-    mem_eff = jnp.where(alive, mem, r - 1)
-    zout_ref[0, :] = z.at[idx_eff.reshape(-1)].set(z_new.reshape(-1))
-    cout_ref[0] = coefv.at[mem_eff].set(coef_new)
+BLOCK_L = 256  # lanes per grid step: the two (D, D, 256) f32 blocks are 1.6 MiB at D = 25
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+def _solve_kernel(chol_ref, gram_ref, rhs_ref, coef_ref, z_ref):
+    rhs = rhs_ref[...]  # (D, BL)
+    d = rhs.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, rhs.shape, 0)
+
+    # Forward substitution  L y = rhs, one row of L per step.
+    y = jnp.zeros_like(rhs)
+    for i in range(d):
+        li = chol_ref[i]  # (D, BL) row i of every lane's L
+        yi = (rhs[i:i + 1] - jnp.sum(li * y, axis=0, keepdims=True)) / li[i:i + 1]
+        y = jnp.where(rows == i, yi, y)
+
+    # Back substitution  L^T x = y, column-oriented so it reads rows of L:
+    # once x_i is known, subtract its contribution L[i, :i] x_i from the
+    # remaining residuals.
+    res = y
+    x = jnp.zeros_like(rhs)
+    for i in reversed(range(d)):
+        li = chol_ref[i]
+        xi = res[i:i + 1] / li[i:i + 1]
+        x = jnp.where(rows == i, xi, x)
+        res = res - li * xi
+    coef_ref[...] = x
+
+    # f_s at the neighborhood points: z_i = sum_j K_s[i, j] x_j.
+    z = jnp.zeros_like(rhs)
+    for i in range(d):
+        zi = jnp.sum(gram_ref[i] * x, axis=0, keepdims=True)
+        z = jnp.where(rows == i, zi, z)
+    z_ref[...] = z
+
+
+@functools.partial(jax.jit, static_argnames=("block_l", "interpret"))
 def color_step_pallas(
-    z: jax.Array,
-    coef: jax.Array,
-    members: jax.Array,
-    idx_m: jax.Array,
-    mask_m: jax.Array,
-    gram_m: jax.Array,
-    chol_m: jax.Array,
-    lam_m: jax.Array,
-    alive_m: jax.Array,
-    alive_z: jax.Array,
-    deliv_m: jax.Array,
+    chol_t: jax.Array,
+    gram_t: jax.Array,
+    rhs_t: jax.Array,
     *,
-    block_m: int = 8,
+    block_l: int = BLOCK_L,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Padded inputs required: M % block_m == 0.  Use ``color_step_fused``
-    for the general-shape wrapper."""
-    b, n_z = z.shape
-    _, r, d = coef.shape
-    m = members.shape[0]
-    assert idx_m.shape == (m, d), (idx_m.shape, m, d)
-    assert gram_m.shape == (b, m, d, d) and chol_m.shape == (b, m, d, d)
-    assert alive_m.shape == (m,), (alive_m.shape, m)
-    assert alive_z.shape == (n_z,), (alive_z.shape, n_z)
-    assert deliv_m.shape == (m, d), (deliv_m.shape, m, d)
-    assert m % block_m == 0, (m, block_m)
-    grid = (b, m // block_m)
+    """(coef_new, z_new), both (D, L), from lane-major chol_t / gram_t
+    (D, D, L) and rhs_t (D, L).  Padded inputs required: L % block_l == 0.
+    Use ``color_solve`` for the general-shape wrapper."""
+    d, lanes = rhs_t.shape
+    assert chol_t.shape == gram_t.shape == (d, d, lanes)
+    assert lanes % block_l == 0, (lanes, block_l)
+    mat = pl.BlockSpec((d, d, block_l), lambda j: (0, 0, j))
+    vec = pl.BlockSpec((d, block_l), lambda j: (0, j))
+    out = jax.ShapeDtypeStruct(rhs_t.shape, rhs_t.dtype)
     return pl.pallas_call(
-        _color_step_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_z), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, r, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((block_m,), lambda b, j: (j,)),
-            pl.BlockSpec((block_m, d), lambda b, j: (j, 0)),
-            pl.BlockSpec((1, block_m, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_m, d, d), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, block_m, d, d), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((block_m,), lambda b, j: (j,)),
-            pl.BlockSpec((block_m,), lambda b, j: (j,)),
-            pl.BlockSpec((n_z,), lambda b, j: (0,)),
-            pl.BlockSpec((block_m, d), lambda b, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_z), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, r, d), lambda b, j: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(z.shape, z.dtype),
-            jax.ShapeDtypeStruct(coef.shape, coef.dtype),
-        ],
+        _solve_kernel,
+        grid=(lanes // block_l,),
+        in_specs=[mat, mat, vec],
+        out_specs=[vec, vec],
+        out_shape=[out, out],
         interpret=interpret,
-    )(
-        z, coef, members, idx_m, mask_m, gram_m, chol_m, lam_m, alive_m,
-        alive_z, deliv_m,
-    )
+    )(chol_t, gram_t, rhs_t)
 
 
-def color_step_fused(
-    z: jax.Array,
-    coef: jax.Array,
-    members: jax.Array,
-    idx_m: jax.Array,
-    mask_m: jax.Array,
-    gram_m: jax.Array,
+def color_solve(
     chol_m: jax.Array,
-    lam_m: jax.Array,
-    alive_m: jax.Array | None = None,
-    alive_z: jax.Array | None = None,
-    deliv_m: jax.Array | None = None,
+    gram_m: jax.Array,
+    rhs: jax.Array,
     *,
-    block_m: int = 8,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """General-shape wrapper: one fused color step for all B fields.
+    """One color's local solves for all B fields: chol_m / gram_m
+    (B, M, D, D), rhs (B, M, D) -> (coef_new, z_new), both (B, M, D).
 
-    z (B, NZ); coef (B, n+1, D); members (M,) int; idx_m (M, D) int;
-    mask_m (B, M, D) bool; gram_m/chol_m (B, M, D, D); lam_m (M,);
-    alive_m (M,) bool member liveness and alive_z (NZ,) bool message-slot
-    liveness (None = fully alive) — the network lifecycle's mask operands:
-    scatters from dead members or onto dead slots redirect to the
-    sentinels so those slots and coefficient rows KEEP their values.
-    deliv_m (M, D) bool per-lane link delivery (None = all delivered,
-    repro.core.faults): an undelivered lane redirects its MESSAGE write
-    to the sentinel the same way (hold-last-value) while the
-    coefficient row still updates — compute is local, only the radio
-    drops.  Returns the updated (z, coef).
-
-    The lane axis is padded to a block multiple with inert lanes (sentinel
-    member row, sentinel slot ids, identity Cholesky): they solve to exact
-    zeros and scatter them onto the sentinels, which are invariantly zero.
+    The B*M systems are moved onto the lane axis and padded to the lane
+    block with inert lanes (identity factor, zero rhs), which solve to
+    exact zeros and are sliced off.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, n_z = z.shape
-    _, r, d = coef.shape
-    m = members.shape[0]
-    if alive_m is None:
-        alive_m = jnp.ones((m,), bool)
-    if alive_z is None:
-        alive_z = jnp.ones((n_z,), bool)
-    if deliv_m is None:
-        deliv_m = jnp.ones((m, d), bool)
-    block_m = min(block_m, max(1, m))
-    pad = (-m) % block_m
+    b, m, d = rhs.shape
+    lanes = b * m
+    block_l, l_pad = fit_block(lanes, BLOCK_L)
+    pad = l_pad - lanes
+
+    def to_lanes(a):  # (B, M, ...) -> (..., L)
+        a = jnp.moveaxis(a.reshape((lanes,) + a.shape[2:]), 0, -1)
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+
+    chol_t = to_lanes(chol_m)
     if pad:
-        members = jnp.concatenate(
-            [members, jnp.full((pad,), r - 1, members.dtype)]
-        )
-        idx_m = jnp.concatenate(
-            [idx_m, jnp.full((pad, d), n_z - 1, idx_m.dtype)]
-        )
-        mask_m = jnp.concatenate(
-            [mask_m, jnp.zeros((b, pad, d), mask_m.dtype)], axis=1
-        )
-        gram_m = jnp.concatenate(
-            [gram_m, jnp.zeros((b, pad, d, d), gram_m.dtype)], axis=1
-        )
-        eye = jnp.broadcast_to(jnp.eye(d, dtype=chol_m.dtype), (b, pad, d, d))
-        chol_m = jnp.concatenate([chol_m, eye], axis=1)
-        lam_m = jnp.concatenate([lam_m, jnp.ones((pad,), lam_m.dtype)])
-        alive_m = jnp.concatenate([alive_m, jnp.ones((pad,), alive_m.dtype)])
-        deliv_m = jnp.concatenate(
-            [deliv_m, jnp.ones((pad, d), deliv_m.dtype)]
-        )
-    return color_step_pallas(
-        z, coef,
-        members.astype(jnp.int32), idx_m.astype(jnp.int32),
-        mask_m.astype(jnp.int8), gram_m, chol_m, lam_m,
-        alive_m.astype(jnp.int8), alive_z.astype(jnp.int8),
-        deliv_m.astype(jnp.int8),
-        block_m=block_m, interpret=interpret,
+        inert = (jnp.arange(l_pad) >= lanes).astype(chol_t.dtype)
+        chol_t = chol_t + jnp.eye(d, dtype=chol_t.dtype)[:, :, None] * inert
+    coef_t, z_t = color_step_pallas(
+        chol_t, to_lanes(gram_m), to_lanes(rhs),
+        block_l=block_l, interpret=auto_interpret(interpret),
     )
+
+    def from_lanes(a):  # (D, L) -> (B, M, D)
+        return jnp.moveaxis(a[:, :lanes], 0, -1).reshape(b, m, d)
+
+    return from_lanes(coef_t), from_lanes(z_t)

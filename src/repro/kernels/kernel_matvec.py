@@ -1,19 +1,22 @@
 """Fused RBF kernel-matvec Pallas kernel — the paper's testing-phase hot spot.
 
-Computes  out[q] = sum_j coef[j] * exp(-gamma * ||xq[q] - anchors[j]||^2)
-without materializing the (Q, N) Gram matrix in HBM.
+Computes  out[b, q] = sum_j coef[b, j] * exp(-gamma * ||xq[q] - anchors[b, j]||^2)
+for B kernel expansions against one shared query grid, without
+materializing the (Q, N) Gram matrix in HBM.
 
-TPU adaptation (DESIGN.md Sec. 2): FlashAttention-style streaming.  Queries
-and anchors are tiled into VMEM blocks of (BQ, d) / (BN, d); the pairwise
-squared distances for one (BQ, BN) tile are produced by two MXU matmuls
-(expanded-square form), exponentiated on the VPU, and immediately contracted
-against the coefficient block.  Only the (BQ,) accumulator ever returns to
-HBM, so HBM traffic is O(Q + N) instead of O(Q * N).
+FlashAttention-style streaming.  Per grid step (b, i, j) one (BQ, BN)
+tile of squared distances is built on the VPU from the (BQ, d) query
+block and the lane-dense (d, BN) anchor block (the direct sum of d
+coordinate differences, exact in f32), exponentiated, and contracted
+against the (1, BN) coefficient row on the MXU into the lane-dense
+(1, BQ) output block, which stays resident in VMEM across the anchor
+axis.  HBM traffic is O(Q + B*N) instead of O(B*Q*N).
 
-Grid: (Q/BQ, N/BN) with the anchor dimension innermost so each output block
-accumulates across anchor tiles in VMEM.  Block sizes default to 128/512 —
-MXU-aligned (multiples of 128) with a VMEM working set of
-BQ*d + BN*d + BQ*BN floats ≈ 0.3 MB, far under the ~16 MB v5e VMEM budget.
+Layout (what Mosaic accepts on v5e): the field axis is a squeezed block
+dim and the anchor and query axes are the lane axes of their blocks —
+anchors are stored (B, d, N), never (B, N, d), whose two-wide minor dim
+would pad 64x in HBM.  BN and BQ are multiples of 128 or the whole
+padded axis (``ops.kernel_matvec`` picks them).
 """
 
 from __future__ import annotations
@@ -26,95 +29,22 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(xq_ref, an_ref, coef_ref, out_ref, *, gamma: float):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    xq = xq_ref[...].astype(jnp.float32)  # (BQ, d)
-    an = an_ref[...].astype(jnp.float32)  # (BN, d)
-    coef = coef_ref[...].astype(jnp.float32)  # (BN,)
-
-    sq_q = jnp.sum(xq * xq, axis=-1)[:, None]  # (BQ, 1)
-    sq_a = jnp.sum(an * an, axis=-1)[None, :]  # (1, BN)
-    cross = jax.lax.dot_general(
-        xq,
-        an,
-        (((1,), (1,)), ((), ())),
+    xq = xq_ref[...]  # (BQ, d)
+    an = an_ref[...]  # (d, BN)
+    d2 = jnp.zeros((xq.shape[0], an.shape[1]), jnp.float32)
+    for c in range(an.shape[0]):
+        diff = xq[:, c:c + 1] - an[c:c + 1, :]
+        d2 = d2 + diff * diff
+    k = jnp.exp(-gamma * d2)  # (BQ, BN)
+    out_ref[...] += jax.lax.dot_general(
+        coef_ref[...], k, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
-    )  # (BQ, BN) on the MXU
-    d2 = jnp.maximum(sq_q + sq_a - 2.0 * cross, 0.0)
-    k = jnp.exp(-gamma * d2)
-    out_ref[...] += k @ coef
-
-
-def _batched_kernel(xq_ref, an_ref, coef_ref, out_ref, *, gamma: float):
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    xq = xq_ref[...].astype(jnp.float32)  # (BQ, d)
-    an = an_ref[0].astype(jnp.float32)  # (BN, d) — this field's anchor tile
-    coef = coef_ref[0].astype(jnp.float32)  # (BN,)
-
-    sq_q = jnp.sum(xq * xq, axis=-1)[:, None]
-    sq_a = jnp.sum(an * an, axis=-1)[None, :]
-    cross = jax.lax.dot_general(
-        xq,
-        an,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (BQ, BN) on the MXU
-    d2 = jnp.maximum(sq_q + sq_a - 2.0 * cross, 0.0)
-    k = jnp.exp(-gamma * d2)
-    out_ref[0, :] += k @ coef
-
-
-@functools.partial(
-    jax.jit, static_argnames=("gamma", "block_q", "block_n", "interpret")
-)
-def kernel_matvec_batched_pallas(
-    xq: jax.Array,
-    anchors: jax.Array,
-    coef: jax.Array,
-    *,
-    gamma: float = 1.0,
-    block_q: int = 128,
-    block_n: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Multi-field evaluation: out[b, q] = sum_j coef[b, j] K(xq[q], anchors[b, j]).
-
-    Queries are shared across the B fields (the serving pattern: one request
-    grid, many concurrent workloads); anchors/coefficients are per-field.
-    Grid (B, Q/BQ, N/BN) with the anchor axis innermost so each (b, q-block)
-    accumulator stays resident in VMEM across anchor tiles — the same
-    streaming contraction as the single-field kernel, amortizing the query
-    tile loads over all B fields.
-
-    Padded inputs required: Q % block_q == 0, N % block_n == 0.  Use
-    `repro.kernels.ops.kernel_matvec` for the general-shape wrapper.
-    """
-    q, d = xq.shape
-    b, n, _ = anchors.shape
-    assert coef.shape == (b, n), (coef.shape, b, n)
-    assert q % block_q == 0 and n % block_n == 0, (q, n, block_q, block_n)
-    grid = (b, q // block_q, n // block_n)
-    return pl.pallas_call(
-        functools.partial(_batched_kernel, gamma=gamma),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, d), lambda b, i, j: (i, 0)),
-            pl.BlockSpec((1, block_n, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i, j: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((b, q), jnp.float32),
-        interpret=interpret,
-    )(xq, anchors, coef)
+    )  # (1, BQ) on the MXU
 
 
 @functools.partial(
@@ -130,23 +60,24 @@ def kernel_matvec_pallas(
     block_n: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Padded inputs required: Q % block_q == 0, N % block_n == 0.
+    """out (B, 1, Q) from xq (Q, d), anchors (B, d, N), coef (B, 1, N), all f32.
 
-    Use `repro.kernels.ops.kernel_matvec` for the general-shape wrapper.
+    Padded inputs required: Q % block_q == 0, N % block_n == 0.  Use
+    ``repro.kernels.ops.kernel_matvec`` for the general-shape wrapper.
     """
     q, d = xq.shape
-    n, _ = anchors.shape
+    b, _, n = anchors.shape
+    assert coef.shape == (b, 1, n), (coef.shape, b, n)
     assert q % block_q == 0 and n % block_n == 0, (q, n, block_q, block_n)
-    grid = (q // block_q, n // block_n)
     return pl.pallas_call(
         functools.partial(_kernel, gamma=gamma),
-        grid=grid,
+        grid=(b, q // block_q, n // block_n),
         in_specs=[
-            pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
+            pl.BlockSpec((block_q, d), lambda b, i, j: (i, 0)),
+            pl.BlockSpec((None, d, block_n), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((None, 1, block_n), lambda b, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_q,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, q), jnp.float32),
         interpret=interpret,
     )(xq, anchors, coef)

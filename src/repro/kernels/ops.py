@@ -1,9 +1,8 @@
 """Jit'd general-shape wrappers around the Pallas kernels.
 
-These handle padding to block multiples, choose interpret mode automatically
-on non-TPU backends (this container is CPU: the kernel bodies execute in
-Python via the Pallas interpreter, which is the validation mode), and slice
-results back to the caller's shapes.
+These pad to block multiples, pick block shapes Mosaic accepts, run the
+kernels in interpret mode only where ``auto_interpret`` says so (the CPU
+test path), and slice results back to the caller's shapes.
 """
 
 from __future__ import annotations
@@ -12,13 +11,32 @@ import jax
 import jax.numpy as jnp
 
 from .gram import rbf_gram_pallas
-from .kernel_matvec import kernel_matvec_batched_pallas, kernel_matvec_pallas
+from .kernel_matvec import kernel_matvec_pallas
 
 
-def _auto_interpret(interpret: bool | None) -> bool:
+def auto_interpret(interpret: bool | None = None) -> bool:
+    """The one interpret-mode switch of every kernel wrapper.
+
+    ``None`` means: compiled on a TPU, interpreted anywhere else (the CPU
+    test path).  On a TPU backend this is always False, so no kernel on
+    the chip path runs interpreted.
+    """
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
+
+
+def fit_block(size: int, block: int, align: int = 128) -> tuple[int, int]:
+    """(block, padded size) for one blocked axis that Mosaic accepts.
+
+    An axis that fits in one block is one whole-axis block; otherwise the
+    block is rounded up to an ``align`` multiple (the lane width for the
+    last block dim) and the axis is padded to a block multiple.
+    """
+    if size <= block:
+        return size, size
+    block = -(-block // align) * align
+    return block, -(-size // block) * block
 
 
 def _pad_dim(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -62,7 +80,8 @@ def kernel_matvec(
     Multi-field batching: pass coef as (B, N) — and optionally anchors as
     (B, N, d) for per-field anchor sets (streaming problems) — to evaluate B
     kernel expansions against one shared query grid in a single fused Pallas
-    launch; returns (B, Q).  Single-field (N,) coef returns (Q,) as before.
+    launch; returns (B, Q).  Single-field (N,) coef returns (Q,) through
+    the same kernel with B = 1.
 
     Padding is exact: padded anchors carry coef 0 (zero contribution) and
     padded query rows are sliced off.  The query axis is padded to its
@@ -70,51 +89,26 @@ def kernel_matvec(
     one anchor set lower O(log Q) distinct programs, not O(#sizes).
     """
     q = xq.shape[0]
-    q_pad = bucket_rows(q)
     coef = jnp.asarray(coef, jnp.float32)
     anchors = jnp.asarray(anchors, jnp.float32)
-    if coef.ndim == 2:
-        b, n = coef.shape
-        if anchors.ndim == 2:
-            anchors = jnp.broadcast_to(anchors[None], (b,) + anchors.shape)
-        block_q = min(block_q, q_pad)
-        block_n = min(block_n, max(8, n))
-        # q <= q_pad, so padding to a q_pad multiple lands exactly on the
-        # bucket; the outer pad only matters for non-power-of-two block_q.
-        xq_p = _pad_rows(
-            _pad_rows(jnp.asarray(xq, jnp.float32), q_pad), block_q
-        )
-        an_p = _pad_dim(anchors, 1, block_n)
-        coef_p = _pad_dim(coef, 1, block_n)
-        out = kernel_matvec_batched_pallas(
-            xq_p,
-            an_p,
-            coef_p,
-            gamma=gamma,
-            block_q=block_q,
-            block_n=block_n,
-            interpret=_auto_interpret(interpret),
-        )
-        return out[:, :q]
-
-    n = anchors.shape[0]
-    block_q = min(block_q, q_pad)
-    block_n = min(block_n, max(8, n))
-    xq_p = _pad_rows(
-        _pad_rows(jnp.asarray(xq, jnp.float32), q_pad), block_q
-    )
-    an_p = _pad_rows(anchors, block_n)
-    coef_p = _pad_rows(coef, block_n)
+    single = coef.ndim == 1
+    if single:
+        coef, anchors = coef[None], anchors[None]
+    b, n = coef.shape
+    if anchors.ndim == 2:
+        anchors = jnp.broadcast_to(anchors[None], (b,) + anchors.shape)
+    block_q, q_pad = fit_block(bucket_rows(q), block_q)
+    block_n, n_pad = fit_block(n, block_n)
     out = kernel_matvec_pallas(
-        xq_p,
-        an_p,
-        coef_p,
+        _pad_rows(jnp.asarray(xq, jnp.float32), q_pad),
+        jnp.swapaxes(_pad_dim(anchors, 1, n_pad), 1, 2),
+        _pad_dim(coef, 1, n_pad)[:, None, :],
         gamma=gamma,
         block_q=block_q,
         block_n=block_n,
-        interpret=_auto_interpret(interpret),
-    )
-    return out[:q]
+        interpret=auto_interpret(interpret),
+    )[:, 0, :q]
+    return out[0] if single else out
 
 
 def rbf_gram(
@@ -137,7 +131,7 @@ def rbf_gram(
         gamma=gamma,
         block_m=block_m,
         block_n=block_n,
-        interpret=_auto_interpret(interpret),
+        interpret=auto_interpret(interpret),
     )
     return out[:m, :n]
 
@@ -179,7 +173,7 @@ def ssd_chunked_fused(
 
     y_intra = ssd_intra_pallas(
         x, dt, da_cum.reshape(b, sp, hp), bmat, cmat,
-        chunk=chunk, block_h=block_h, interpret=_auto_interpret(interpret),
+        chunk=chunk, block_h=block_h, interpret=auto_interpret(interpret),
     )
 
     # chunk boundary states + inter-chunk recurrence (same math as the ref)

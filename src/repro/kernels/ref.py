@@ -1,4 +1,8 @@
-"""Pure-jnp oracles for the Pallas kernels (ground truth in tests/benches)."""
+"""Pure-jnp oracles for the Pallas kernels (ground truth in tests/benches).
+
+Matmuls ask for ``precision="highest"`` so the oracles stay f32-exact on
+a TPU, where the default is one bf16 pass.
+"""
 
 from __future__ import annotations
 
@@ -9,7 +13,8 @@ import jax.numpy as jnp
 def _sq_dists(x1: jax.Array, x2: jax.Array) -> jax.Array:
     sq1 = jnp.sum(x1 * x1, axis=-1)[:, None]
     sq2 = jnp.sum(x2 * x2, axis=-1)[None, :]
-    return jnp.maximum(sq1 + sq2 - 2.0 * (x1 @ x2.T), 0.0)
+    cross = jnp.matmul(x1, x2.T, precision="highest")
+    return jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
 
 
 def rbf_gram_ref(x1: jax.Array, x2: jax.Array, gamma: float) -> jax.Array:
@@ -25,7 +30,7 @@ def kernel_matvec_ref(
     Materializes the full (Q, N) Gram matrix — the thing the Pallas kernel
     avoids doing in HBM.
     """
-    return rbf_gram_ref(xq, anchors, gamma) @ coef
+    return jnp.matmul(rbf_gram_ref(xq, anchors, gamma), coef, precision="highest")
 
 
 def kernel_matvec_batched_ref(
@@ -36,7 +41,7 @@ def kernel_matvec_batched_ref(
     anchors: (B, N, d) per-field anchor sets; coef: (B, N).  Materializes the
     full (B, Q, N) Gram tensor the batched Pallas kernel streams through VMEM.
     """
-    return jax.vmap(lambda an, c: rbf_gram_ref(xq, an, gamma) @ c)(anchors, coef)
+    return jax.vmap(lambda an, c: kernel_matvec_ref(xq, an, c, gamma))(anchors, coef)
 
 
 def local_batched_solve_ref(

@@ -82,6 +82,7 @@ from repro.core import faults as faults_mod
 from repro.core.serving import plan_add_sensor, plan_remove_sensor
 from repro.core.sn_train import effective_coef
 from repro.kernels.ops import bucket_rows
+from repro.launch.cache import enable_compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -699,6 +700,7 @@ def main(argv=None):
                          "snapshot matches the last checkpoint's probe "
                          "answers + state digest bitwise, then exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     pos, prob, state, rng = _build_problem(args)
     cfg = DaemonConfig(

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import decode_step, init_cache, init_params, prefill
 
 # Hardened launch environment (the HomebrewNLP run.sh pattern, see
@@ -503,6 +504,7 @@ def main():
     ns, rest = pre.parse_known_args()
     if ns.hardened_env and os.environ.get(_HARDENED_GUARD) != "1":
         _reexec_hardened()  # never returns
+    enable_compile_cache()
     if ns.mode == "daemon":
         from repro.launch import daemon
 
