@@ -1,0 +1,93 @@
+"""The traffic generator and the loops that drive it (CPU, tiny sizes)."""
+
+import time
+
+import numpy as np
+
+import deploy
+import drivers
+import traffic
+from tinycfg import tiny
+
+MIX = {"rate_per_s": 40.0,
+       "requests": [{"share": 0.8, "kind": "points", "rows_min": 1, "rows_max": 8},
+                    {"share": 0.2, "kind": "tile", "grid": 16, "tile_side": 0.125}]}
+BOX = (np.array([-0.9, -0.9]), np.array([0.9, 0.9]))
+
+
+def test_same_seed_same_schedule():
+    a, b = (traffic.open_requests(MIX, 10.0, deploy.streams(2**40 + 7, 1)[0], BOX)
+            for _ in range(2))
+    assert np.array_equal(a.due, b.due) and a.kinds == b.kinds
+    assert all(np.array_equal(x, y) for x, y in zip(a.queries, b.queries))
+    c = traffic.open_requests(MIX, 10.0, deploy.streams(8, 1)[0], BOX)
+    assert not np.array_equal(a.due, c.due)
+    # every seed gets the same amount of work
+    assert len(c.due) == len(a.due) == 400
+    assert sorted(len(q) for q in c.queries)[-80:] == [256] * 80
+    assert sum(len(q) for q in a.queries) == sum(len(q) for q in c.queries)
+
+
+def test_city_arrivals_never_repeat_a_pair():
+    spec, _ = tiny("city2k-daemon")
+    cfg = spec.config("city-aq-2k")
+    pos = deploy.positions(cfg)
+    fields = deploy.Fields(cfg["fields"], np.random.default_rng(0), cfg["noise"])
+    arr = traffic.reports(cfg, pos, fields, 30.0, np.random.default_rng(5))
+    pairs = set(zip(arr.fields.tolist(), arr.sensors.tolist()))
+    assert len(pairs) == len(arr.fields) == 4 * round(2000 * 30 / 120)
+    assert np.all(np.diff(arr.due) >= 0)
+    assert set(arr.fields.tolist()) == {15, 31, 47, 63}  # the newest interval
+
+
+def _ctx(cell, seconds, **kw):
+    spec, cfg = tiny(cell)
+    w = spec.cell(cell)
+    mix = dict(spec.traffic(w["traffic"]), **kw)
+    return drivers.Context(cfg=cfg, mix=mix, seed=3, seconds=seconds,
+                           t_start=time.perf_counter())
+
+
+def test_open_loop_latency_counts_from_due_time(monkeypatch):
+    from repro.launch.daemon import Daemon
+
+    pump = Daemon.pump
+    state = {"calls": 0}
+    stall = 0.6
+
+    def stalled(self):
+        state["calls"] += 1
+        if state["calls"] == 3:
+            time.sleep(stall)
+        return pump(self)
+
+    ctx = _ctx("city2k-daemon", 2.0, rate_per_s=30.0)
+    monkeypatch.setattr(drivers, "_warm_daemon", lambda *a: None)
+    monkeypatch.setattr(Daemon, "pump", stalled)
+    rec, _ = drivers.open_loop(ctx)
+    lat = np.asarray(rec.latencies_ms)
+    assert rec.failed == 0 and len(lat) == 60
+    # requests due during the stall were submitted after it, yet their
+    # latency counts from when they were due
+    assert (lat > 0.5 * stall * 1e3).sum() >= 3
+
+
+def test_closed_loop_keeps_clients_outstanding(monkeypatch):
+    from repro.launch.daemon import Daemon
+
+    pump = Daemon.pump
+    queued = []
+
+    def counting(self):
+        queued.append((id(self), len(self._queries)))
+        return pump(self)
+
+    ctx = _ctx("lab54-history", 1.0)
+    monkeypatch.setattr(Daemon, "pump", counting)
+    rec, _ = drivers.closed_loop(ctx)
+    clients = ctx.mix["clients"]
+    served = max({d for d, _ in queued}, key=[d for d, _ in queued].count)
+    queued = [q for d, q in queued if d == served]  # not the warm-up daemon's
+    assert len(queued) > 5
+    assert all(q == clients for q in queued[:-1])  # the last pump drains after close
+    assert rec.notes["requests"] >= clients * (len(queued) - 2)
