@@ -37,6 +37,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import faults as faults_mod
 from . import sn_train
 from .sn_train import SNTrainProblem, SNTrainState, weighted_norm_sq
@@ -165,19 +167,23 @@ def watch_sweeps(
     spr = config.sweeps_per_round
 
     def run_round(problem, state, key):
-        key, sub = jax.random.split(key)
-        if model is None:
-            cand = sn_train.colored_sweep(
-                problem, state, n_sweeps=spr, engine=engine
-            )
-        else:
-            cand = faults_mod.faulty_sweep(
-                problem, state, model, sub, n_sweeps=spr, engine=engine
-            )
-        norm, resid = _round_metrics(problem, state, cand)
-        return cand, np.atleast_1d(np.asarray(norm)), np.atleast_1d(
-            np.asarray(resid)
-        ), key
+        with obs.span("watch.round"):
+            with obs.span("watch.launch"):
+                key, sub = jax.random.split(key)
+                if model is None:
+                    cand = sn_train.colored_sweep(
+                        problem, state, n_sweeps=spr, engine=engine
+                    )
+                else:
+                    cand = faults_mod.faulty_sweep(
+                        problem, state, model, sub, n_sweeps=spr,
+                        engine=engine,
+                    )
+                norm, resid = _round_metrics(problem, state, cand)
+            with obs.span("watch.sync"):
+                norm = np.atleast_1d(np.asarray(norm))
+                resid = np.atleast_1d(np.asarray(resid))
+        return cand, norm, resid, key
 
     norm_prev = np.atleast_1d(np.asarray(weighted_norm_sq(problem, state)))
     resid = np.full_like(norm_prev, np.inf)

@@ -288,24 +288,27 @@ def knn_select_valid(
     quant bench and tests use it to quantify the selection-flip cost.
     Cell lookup stays full-precision.
     """
-    cid = query_cells(plan, xq)  # (Q,) — always full precision
-    cand = plan.cells[cid]  # (Q, K_max)
-    cmask = plan.cell_mask[cid]  # (Q, K_max)
-    if alive is not None:
-        cmask = cmask & (alive[cand] != 0)
-    pos_pad = jnp.concatenate(
-        [positions, jnp.zeros((1, positions.shape[1]), positions.dtype)]
-    )
-    cpos = pos_pad[cand]  # (Q, K_max, d)
-    if compute_dtype is not None:
-        cdt = jnp.dtype(compute_dtype)
-        ar = cdt if cdt.itemsize >= 4 else jnp.dtype(jnp.float32)
-        xq = xq.astype(cdt).astype(ar)  # round to storage, compute wide
-        cpos = cpos.astype(cdt).astype(ar)
-    d2 = jnp.sum((xq[:, None, :] - cpos) ** 2, axis=-1)
-    d2 = jnp.where(cmask, d2, jnp.inf)
-    neg, top = jax.lax.top_k(-d2, k)  # (Q, k) candidate positions
-    return jnp.take_along_axis(cand, top, axis=1), jnp.isfinite(neg)
+    with jax.named_scope("cell_lookup"):
+        cid = query_cells(plan, xq)  # (Q,) — always full precision
+    with jax.named_scope("candidates"):
+        cand = plan.cells[cid]  # (Q, K_max)
+        cmask = plan.cell_mask[cid]  # (Q, K_max)
+        if alive is not None:
+            cmask = cmask & (alive[cand] != 0)
+        pos_pad = jnp.concatenate(
+            [positions, jnp.zeros((1, positions.shape[1]), positions.dtype)]
+        )
+        cpos = pos_pad[cand]  # (Q, K_max, d)
+        if compute_dtype is not None:
+            cdt = jnp.dtype(compute_dtype)
+            ar = cdt if cdt.itemsize >= 4 else jnp.dtype(jnp.float32)
+            xq = xq.astype(cdt).astype(ar)  # round to storage, compute wide
+            cpos = cpos.astype(cdt).astype(ar)
+        d2 = jnp.sum((xq[:, None, :] - cpos) ** 2, axis=-1)
+        d2 = jnp.where(cmask, d2, jnp.inf)
+    with jax.named_scope("top_k"):
+        neg, top = jax.lax.top_k(-d2, k)  # (Q, k) candidate positions
+        return jnp.take_along_axis(cand, top, axis=1), jnp.isfinite(neg)
 
 
 def knn_select(
@@ -342,24 +345,27 @@ def _eval_selected(
     cdt = None if compute_dtype is None else jnp.dtype(compute_dtype)
 
     def per_query(x, sel_q, valid_q):
-        npos = nbr_pos[sel_q]  # (k, D, d)
-        cf = jnp.where(nbr_mask[sel_q], coef[sel_q], 0.0)  # (k, D)
-        if cdt is not None:
-            ar = x.dtype if x.dtype.itemsize >= 4 else jnp.dtype(jnp.float32)
-            npos = npos.astype(cdt).astype(ar)
-        if cdt is not None and kernel.name == "rbf":
-            # Direct (x - x_j)^2 form, not the matmul expansion the generic
-            # kernel uses — matches the Pallas kernel bit-for-bit on the
-            # same rounded inputs.
-            dd = jnp.sum((x[None, None, :] - npos) ** 2, axis=-1)  # (k, D)
-            kv = jnp.exp(-kernel.gamma * dd)
-        else:
-            kv = kernel(x[None, :], npos.reshape(k * d_max, d))[0].reshape(
-                k, d_max
-            )
-        f = jnp.sum(kv.astype(cf.dtype) * cf, axis=-1)  # (k,) coef dtype
-        cnt = jnp.sum(valid_q)
-        return jnp.sum(jnp.where(valid_q, f, 0.0)) / jnp.maximum(cnt, 1)
+        with jax.named_scope("anchor_gather"):
+            npos = nbr_pos[sel_q]  # (k, D, d)
+            cf = jnp.where(nbr_mask[sel_q], coef[sel_q], 0.0)  # (k, D)
+        with jax.named_scope("kernel_eval"):
+            if cdt is not None:
+                ar = x.dtype if x.dtype.itemsize >= 4 else jnp.dtype(
+                    jnp.float32
+                )
+                npos = npos.astype(cdt).astype(ar)
+            if cdt is not None and kernel.name == "rbf":
+                # Direct (x - x_j)^2 form, not the matmul expansion the
+                # generic kernel uses — matches the Pallas kernel
+                # bit-for-bit on the same rounded inputs.
+                dd = jnp.sum((x[None, None, :] - npos) ** 2, axis=-1)
+                kv = jnp.exp(-kernel.gamma * dd)  # (k, D)
+            else:
+                kv = kernel(x[None, :], npos.reshape(k * d_max, d))
+                kv = kv[0].reshape(k, d_max)
+            f = jnp.sum(kv.astype(cf.dtype) * cf, axis=-1)  # (k,) coef dtype
+            cnt = jnp.sum(valid_q)
+            return jnp.sum(jnp.where(valid_q, f, 0.0)) / jnp.maximum(cnt, 1)
 
     return jax.vmap(per_query)(xq, sel, valid)
 

@@ -718,22 +718,24 @@ def _color_solve(
     Returns (idx_m (M, D), coef_new (B, M, D), z_new (B, M, D)); the engines
     differ only in how they solve (``local_solve``) and scatter these back.
     """
-    idx_m = nbr_idx[members]  # (M, D) shared across fields
-    live_m = member_mask & alive_row[members]  # (M,) updating members
-    mask_m = (
-        nbr_mask[:, members]
-        & live_m[None, :, None]
-        & alive_slot[idx_m][None]
-    )  # (B, M, D)
-    gram_m = gram[:, members]  # (B, M, D, D)
-    chol_m = chol[:, members]  # (B, M, D, D)
-    lam_m = lam_pad[members]  # (M,)
-    coef_m = coef[:, members]  # (B, M, D)
+    with jax.named_scope("plan_gather"):
+        idx_m = nbr_idx[members]  # (M, D) shared across fields
+        live_m = member_mask & alive_row[members]  # (M,) updating members
+        mask_m = (
+            nbr_mask[:, members]
+            & live_m[None, :, None]
+            & alive_slot[idx_m][None]
+        )  # (B, M, D)
+        gram_m = gram[:, members]  # (B, M, D, D)
+        chol_m = chol[:, members]  # (B, M, D, D)
+        lam_m = lam_pad[members]  # (M,)
+        coef_m = coef[:, members]  # (B, M, D)
 
-    b = z.shape[0]
-    z_nbr = z[:, idx_m.reshape(-1)].reshape(b, *idx_m.shape)  # (B, M, D)
-    rhs = jnp.where(mask_m, z_nbr + lam_m[None, :, None] * coef_m, 0.0)
-    coef_new, z_new = local_solve(chol_m, gram_m, rhs)
+        b = z.shape[0]
+        z_nbr = z[:, idx_m.reshape(-1)].reshape(b, *idx_m.shape)  # (B, M, D)
+        rhs = jnp.where(mask_m, z_nbr + lam_m[None, :, None] * coef_m, 0.0)
+    with jax.named_scope("colour_solve"):
+        coef_new, z_new = local_solve(chol_m, gram_m, rhs)
     return idx_m, coef_new, z_new
 
 
@@ -871,10 +873,11 @@ def _colored_core(
                     deliv_flat,
                 )
             else:
-                z, coef = _apply_plan(
-                    z, coef, z_new, coef_new, plan_z_c, plan_coef_c,
-                    live_m, alive_slot, deliv_flat,
-                )
+                with jax.named_scope("plan_scatter"):
+                    z, coef = _apply_plan(
+                        z, coef, z_new, coef_new, plan_z_c, plan_coef_c,
+                        live_m, alive_slot, deliv_flat,
+                    )
             return (z, coef), None
 
         return color_body

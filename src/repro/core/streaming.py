@@ -241,15 +241,16 @@ def _absorb(
     aw_old = problem.anchor_w[field, sensor]  # (D,)
     aw_s = aw_old * s_vec.astype(aw_old.dtype)
     gram_s = problem.gram[field, sensor] * (s_vec[:, None] * s_vec[None, :])
-    chol_s = problem.chol[field, sensor] * s_vec[:, None].astype(
-        problem.chol.dtype
-    )
-    alpha = jnp.where(
-        is_stream, jnp.sqrt((1.0 - beta_b) * lam_s.astype(gdt)), 0.0
-    )
-    chol_s = jnp.where(
-        beta_b < 1.0, _chol_diag_update(chol_s, alpha), chol_s
-    )
+    with jax.named_scope("chol_update"):
+        chol_s = problem.chol[field, sensor] * s_vec[:, None].astype(
+            problem.chol.dtype
+        )
+        alpha = jnp.where(
+            is_stream, jnp.sqrt((1.0 - beta_b) * lam_s.astype(gdt)), 0.0
+        )
+        chol_s = jnp.where(
+            beta_b < 1.0, _chol_diag_update(chol_s, alpha), chol_s
+        )
 
     # The kernel vector is masked to the EFFECTIVE lanes (occupied & alive):
     # a removed neighbor's lane keeps its occupancy but is factored out of
@@ -270,9 +271,10 @@ def _absorb(
     # Grow-one Cholesky: rows >= k of chol[s] are identity (padded), so the
     # full-shape triangular solve returns w on the valid prefix and zeros
     # elsewhere; only row k of the factor changes.
-    w = jsl.solve_triangular(chol_s, kvec, lower=True)
-    d_new = jnp.sqrt(jnp.maximum(kself + lam_s - jnp.sum(w * w), 1e-12))
-    chol_s = chol_s.at[k, :].set(w.at[k].set(d_new))
+    with jax.named_scope("chol_update"):
+        w = jsl.solve_triangular(chol_s, kvec, lower=True)
+        d_new = jnp.sqrt(jnp.maximum(kself + lam_s - jnp.sum(w * w), 1e-12))
+        chol_s = chol_s.at[k, :].set(w.at[k].set(d_new))
 
     # Every write is gated on `ok`: absorbing into a FULL sensor (argmin of
     # an all-True mask would alias slot 0, a live neighbor) degrades to a

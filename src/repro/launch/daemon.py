@@ -71,6 +71,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import (
     fusion,
     make_serving_plan,
@@ -188,7 +189,7 @@ class TickReceipt(NamedTuple):
         }
 
 
-_ecoef_jit = jax.jit(effective_coef)
+_ecoef_jit = jax.jit(jax.named_scope("ecoef")(effective_coef))
 
 
 def _state_digest(problem, state) -> str:
@@ -277,7 +278,18 @@ class Daemon:
         self.shed = 0
         self.degraded = False
         self.last_tick: TickReceipt | None = None
-        self.buckets_hit: set = set()  # padded dispatch sizes (tests)
+        # always-on counters of the query and arrival paths (``health``)
+        self._counters = {
+            "dispatches": {},  # str(bucket rows) -> dispatches
+            "rows": 0,  # query rows dispatched
+            "padded_rows": 0,  # rows after bucket padding
+            "queue_wait_s_sum": 0.0,  # submit -> start of its dispatch
+            "queue_wait_n": 0,
+            "queue_wait_s_max": 0.0,
+            "serve_traces": 0,  # jaxpr traces while launching dispatches
+            "arrival_rows": 0,  # arrivals taken into absorb windows
+            "arrival_padded_rows": 0,  # window rows after padding
+        }
         self._ema_batch_s: float | None = None
         # initial publish: version 0 serves the (possibly restored) state
         self._snap = self._make_snapshot(0, problem, state, self._plan)
@@ -365,54 +377,92 @@ class Daemon:
         still see their snapshot's buffers.
         """
         answers: list[QueryAnswer] = []
-        while self._queries:
-            snap = self._snap  # one snapshot per dispatch
-            batch = [self._queries.popleft()]
-            rows = batch[0][1].shape[0]
-            while (
-                self._queries
-                and rows + self._queries[0][1].shape[0]
-                <= self.config.max_batch_rows
-            ):
-                nxt = self._queries.popleft()
-                batch.append(nxt)
-                rows += nxt[1].shape[0]
-            self._pending_rows -= rows
-            xq = np.concatenate([b[1] for b in batch], axis=0)
-            q_pad = bucket_rows(rows)
-            if q_pad > rows:  # padded rows are sliced off below: exact
-                xq = np.concatenate(
-                    [xq, np.repeat(xq[-1:], q_pad - rows, axis=0)], axis=0
-                )
-            self.buckets_hit.add(q_pad)
-            t0 = time.perf_counter()
-            out = fusion.fuse(
-                snap.problem, snap.state, xq, "knn",
-                k=self.config.k, engine=self.config.engine,
-                plan=snap.plan, ecoef=snap.ecoef,
-                compute_dtype=self._compute_dtype, prune=snap.keep,
-            )
-            out.block_until_ready()
-            done = time.perf_counter()
-            dt = done - t0
-            self._ema_batch_s = (
-                dt if self._ema_batch_s is None
-                else 0.8 * self._ema_batch_s + 0.2 * dt
-            )
-            vals = np.asarray(out)
-            off = 0
-            for qid, grid, t_submit in batch:
-                q = grid.shape[0]
-                answers.append(QueryAnswer(
-                    id=qid,
-                    values=vals[:, off:off + q],
-                    version=snap.version,
-                    degraded=self.degraded,
-                    latency_s=done - t_submit,
-                ))
-                off += q
-            self.served += len(batch)
+        with obs.span("daemon.pump") as sp:
+            rows = 0
+            while self._queries:
+                rows += self._dispatch(answers)
+            sp["requests"] = len(answers)
+            sp["rows"] = rows
         return answers
+
+    def _dispatch(self, answers: list) -> int:
+        """One coalesced dispatch off the queue's front; appends its
+        answers and returns its rows."""
+        c = self._counters
+        snap = self._snap  # one snapshot per dispatch
+        with obs.span("serve.dispatch") as sp:
+            t_disp = sp.stamp()
+            with obs.span("serve.pack"):
+                batch = [self._queries.popleft()]
+                rows = batch[0][1].shape[0]
+                while (
+                    self._queries
+                    and rows + self._queries[0][1].shape[0]
+                    <= self.config.max_batch_rows
+                ):
+                    nxt = self._queries.popleft()
+                    batch.append(nxt)
+                    rows += nxt[1].shape[0]
+                self._pending_rows -= rows
+                xq = np.concatenate([b[1] for b in batch], axis=0)
+                q_pad = bucket_rows(rows)
+                if q_pad > rows:  # padded rows are sliced off below: exact
+                    xq = np.concatenate(
+                        [xq, np.repeat(xq[-1:], q_pad - rows, axis=0)],
+                        axis=0,
+                    )
+            with obs.span("serve.launch") as launch:
+                t_launch = launch.stamp()
+                traced = obs.traces()[0]
+                out = fusion.fuse(
+                    snap.problem, snap.state, xq, "knn",
+                    k=self.config.k, engine=self.config.engine,
+                    plan=snap.plan, ecoef=snap.ecoef,
+                    compute_dtype=self._compute_dtype, prune=snap.keep,
+                )
+                traced = obs.traces()[0] - traced
+                launch["traces"] = traced
+            with obs.span("serve.wait"):
+                out.block_until_ready()
+            with obs.span("serve.fetch") as fetch:
+                done_ns = fetch.stamp()
+                vals = np.asarray(out)
+                fetch["bytes"] = vals.nbytes
+            with obs.span("serve.answer"):
+                done = done_ns * 1e-9
+                off = 0
+                for qid, grid, t_submit in batch:
+                    q = grid.shape[0]
+                    answers.append(QueryAnswer(
+                        id=qid,
+                        values=vals[:, off:off + q],
+                        version=snap.version,
+                        degraded=self.degraded,
+                        latency_s=done - t_submit,
+                    ))
+                    off += q
+            sp["rows"] = rows
+            sp["bucket"] = q_pad
+            sp["requests"] = len(batch)
+            sp["version"] = snap.version
+        dt = (done_ns - t_launch) * 1e-9
+        self._ema_batch_s = (
+            dt if self._ema_batch_s is None
+            else 0.8 * self._ema_batch_s + 0.2 * dt
+        )
+        self.served += len(batch)
+        key = str(q_pad)
+        c["dispatches"][key] = c["dispatches"].get(key, 0) + 1
+        c["rows"] += rows
+        c["padded_rows"] += q_pad
+        start = t_disp * 1e-9
+        for _, _, t_submit in batch:
+            wait = start - t_submit
+            c["queue_wait_s_sum"] += wait
+            c["queue_wait_s_max"] = max(c["queue_wait_s_max"], wait)
+        c["queue_wait_n"] += len(batch)
+        c["serve_traces"] += traced
+        return rows
 
     # -- trainer-side inputs -----------------------------------------------
 
@@ -481,29 +531,40 @@ class Daemon:
         """
         absorbed = dropped = 0
         w = self.config.arrival_rows
-        while self._arrivals:
-            take = min(len(self._arrivals), w)
-            window = [self._arrivals.popleft() for _ in range(take)]
-            fs = np.array([a[0] for a in window], np.int32)
-            ss = np.array([a[1] for a in window], np.int32)
-            xs = np.stack([a[2] for a in window]).astype(
-                problem.nbr_pos.dtype, copy=False
-            )
-            ys = np.array([a[3] for a in window])
-            a_pad = take if take == w else min(bucket_rows(take), w)
-            fs, ss, xs, ys, real = streaming.pad_arrivals(
-                problem, fs, ss, xs, ys, a_pad
-            )
-            # donate=False ALWAYS: right after a publish the working pair
-            # aliases the published snapshot's buffers — donating them
-            # would delete the arrays queries are still reading.
-            problem, state, rec = streaming.absorb_many(
-                problem, state, fs, ss, xs, ys,
-                donate=False, on_full=self.config.on_full,
-            )
-            ok = np.asarray(rec.absorbed)[real]
-            absorbed += int(ok.sum())
-            dropped += int((~ok).sum())
+        c = self._counters
+        rows0, padded0 = c["arrival_rows"], c["arrival_padded_rows"]
+        with obs.span("tick.absorb") as sp:
+            while self._arrivals:
+                take = min(len(self._arrivals), w)
+                a_pad = take if take == w else min(bucket_rows(take), w)
+                with obs.span("absorb.window") as win:
+                    window = [self._arrivals.popleft() for _ in range(take)]
+                    fs = np.array([a[0] for a in window], np.int32)
+                    ss = np.array([a[1] for a in window], np.int32)
+                    xs = np.stack([a[2] for a in window]).astype(
+                        problem.nbr_pos.dtype, copy=False
+                    )
+                    ys = np.array([a[3] for a in window])
+                    fs, ss, xs, ys, real = streaming.pad_arrivals(
+                        problem, fs, ss, xs, ys, a_pad
+                    )
+                    # donate=False ALWAYS: right after a publish the working
+                    # pair aliases the published snapshot's buffers —
+                    # donating them would delete the arrays queries are
+                    # still reading.
+                    problem, state, rec = streaming.absorb_many(
+                        problem, state, fs, ss, xs, ys,
+                        donate=False, on_full=self.config.on_full,
+                    )
+                    ok = np.asarray(rec.absorbed)[real]
+                    win["rows"] = take
+                    win["padded"] = a_pad
+                absorbed += int(ok.sum())
+                dropped += int((~ok).sum())
+                c["arrival_rows"] += take
+                c["arrival_padded_rows"] += a_pad
+            sp["rows"] = c["arrival_rows"] - rows0
+            sp["padded"] = c["arrival_padded_rows"] - padded0
         return problem, state, absorbed, dropped
 
     def tick(self) -> TickReceipt:
@@ -518,20 +579,33 @@ class Daemon:
         its working state (it may recover next tick) but does not
         publish.  Either unhealthy outcome marks the daemon degraded.
         """
+        with obs.span("daemon.tick") as sp:
+            rc = self._tick()
+            sp["absorbed"] = rc.absorbed
+            sp["published"] = rc.published
+        return rc
+
+    def _tick(self) -> TickReceipt:
         cfg = self.config
         problem, state = self._work
         plan = self._plan
-        problem, state, plan, joins, leaves = self._apply_events(
-            problem, state, plan
-        )
+        with obs.span("tick.events") as sp:
+            problem, state, plan, joins, leaves = self._apply_events(
+                problem, state, plan
+            )
+            sp["joins"] = joins
+            sp["leaves"] = leaves
         problem, state, absorbed, arrival_drops = self._absorb_pending(
             problem, state
         )
         self._key, sub = jax.random.split(self._key)
-        problem, state, receipt = monitor.watch_sweeps(
-            problem, state, model=self._model, key=sub,
-            engine=cfg.train_engine, config=self._watch_cfg,
-        )
+        with obs.span("tick.sweeps") as sp:
+            problem, state, receipt = monitor.watch_sweeps(
+                problem, state, model=self._model, key=sub,
+                engine=cfg.train_engine, config=self._watch_cfg,
+            )
+            sp["rounds"] = receipt.rounds
+            sp["sweeps"] = receipt.sweeps
         self.tick_count += 1
         arrivals_rolled_back = 0
         ckpt_step = None
@@ -553,9 +627,10 @@ class Daemon:
         else:
             self.degraded = False
             published = True
-            self._snap = self._make_snapshot(
-                self._snap.version + 1, problem, state, plan
-            )
+            with obs.span("tick.publish"):
+                self._snap = self._make_snapshot(
+                    self._snap.version + 1, problem, state, plan
+                )
             if (
                 cfg.ckpt_every
                 and cfg.snapshot_dir is not None
@@ -587,7 +662,16 @@ class Daemon:
     # -- health ------------------------------------------------------------
 
     def health(self) -> dict:
-        """Machine-readable health endpoint (plain-JSON types only)."""
+        """Machine-readable health endpoint (plain-JSON types only).
+
+        ``counters`` are running totals since construction: dispatches
+        per padded bucket (``{str(rows): count}``), query ``rows`` and
+        ``padded_rows`` (bucket fill = their ratio), the submit -> dispatch
+        queue wait (``queue_wait_s_sum`` / ``_n`` / ``_max``), the jaxpr
+        traces the dispatches caused (``serve_traces``; a warmed path that
+        keeps tracing retraces per call), and ``arrival_rows`` /
+        ``arrival_padded_rows`` over the absorb windows.
+        """
         t = self.last_tick
         return {
             "schema": "daemon_health/1",
@@ -602,6 +686,10 @@ class Daemon:
             "serve_dtype": self.config.serve_dtype,
             "energy_tau": float(self._energy_tau),
             "pruned": int(self._snap.pruned),
+            "counters": {
+                **self._counters,
+                "dispatches": dict(self._counters["dispatches"]),
+            },
             "last_tick": None if t is None else {
                 "tick": t.tick,
                 "published": t.published,
