@@ -75,11 +75,10 @@ def _build(n, b, radius, gamma, lam, spares, seed=0):
 
 def _cache_sizes():
     """Every program the daemon's steady state dispatches: the bucketed
-    serving pair, the supervised faulty trainer, absorbs, churn repairs,
+    serving program, the supervised faulty trainer, absorbs, churn repairs,
     and the per-publish effective-coefficient read."""
     fns = (
-        serving.knn_select_valid,
-        serving._eval_selected,
+        serving._knn_plan,
         serving.plan_add_sensor,
         serving.plan_remove_sensor,
         faults._faulty_colored,

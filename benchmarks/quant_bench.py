@@ -77,10 +77,10 @@ def _problem(n, b, radius, lam, seed=0):
 
 
 def _tracked_caches():
-    from repro.core.serving import _eval_selected, knn_select_valid
+    from repro.core.serving import _knn_plan
     from repro.kernels.knn_fuse import knn_fuse_pallas
 
-    return (knn_fuse_pallas, knn_select_valid, _eval_selected)
+    return (knn_fuse_pallas, _knn_plan)
 
 
 def _grid_cells(prob, state, plan_cap, taus):

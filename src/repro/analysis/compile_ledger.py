@@ -71,6 +71,8 @@ def _entries() -> list[LedgerEntry]:
         e("faults.serial", C + "faults:_faulty_serial", FROZEN),
         e("faults.robust", C + "faults:_faulty_robust", FROZEN),
         # --- serving: O(log Q) bucketed programs on the query axis
+        e("serving.knn_plan", C + "serving:_knn_plan", BUCKETS,
+          "the plan engine: select + evaluate, one program per bucket"),
         e("serving.select", C + "serving:knn_select_valid", BUCKETS),
         e("serving.eval", C + "serving:_eval_selected", BUCKETS),
         e("serving.knn_kernel",
@@ -128,18 +130,17 @@ LEDGER: dict[str, LedgerEntry] = {x.name: x for x in _entries()}
 # Named groups matching the repo's cache-pinning consumers.
 GROUPS: dict[str, tuple[str, ...]] = {
     # the daemon's serving path: programs grow only with new buckets
-    "daemon": ("serving.select", "serving.eval"),
+    "daemon": ("serving.knn_plan",),
     # fault drills: toggling rates on/off reuses compiled programs
     "faults": ("faults.colored",),
     # quantized serving: tau sweep + bucket reuse compile nothing
-    "quant": ("serving.knn_kernel", "serving.select", "serving.eval",
-              "pruning.keep"),
+    "quant": ("serving.knn_kernel", "serving.knn_plan", "pruning.keep"),
 }
 
 
 def churn_group(*, on_full: str = "drop", donate: bool = True) -> tuple[str, ...]:
     """The program set one churn round exercises (join + leave + absorb +
-    refresh sweep + plan repairs + serving select)."""
+    refresh sweep + plan repairs + the plan engine's query)."""
     v = "donate" if donate else "copy"
     policy = "evict" if on_full == "evict" else "drop"
     return (
@@ -147,7 +148,7 @@ def churn_group(*, on_full: str = "drop", donate: bool = True) -> tuple[str, ...
         f"stream.remove.{v}",
         f"stream.absorb_many.{policy}.{v}",
         "sweep.colored",
-        "serving.select",
+        "serving.knn_plan",
         "serving.plan_add",
         "serving.plan_remove",
     )

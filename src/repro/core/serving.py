@@ -420,6 +420,21 @@ def knn_fuse(
             "(rebuild with make_serving_plan(problem, k=...))"
         )
     cdt_name = _norm_compute_dtype(compute_dtype)
+    if engine == "plan":
+        # One compiled program per (bucket, kernel, k, dtype): a warm call
+        # traces nothing and launches once through jit's cached path.
+        if ecoef is None:
+            coef, anchor_w = state.coef, problem.anchor_w
+        else:
+            coef, anchor_w = ecoef, None
+        if not isinstance(xq, jax.Array):
+            xq = np.asarray(xq)
+        return _knn_plan(
+            problem.kernel, plan, problem.topology.positions,
+            problem.nbr_pos, problem.nbr_mask, coef, anchor_w,
+            problem.alive, prune, xq, k=k, compute_dtype=cdt_name,
+        )
+
     alive = problem.alive
     if prune is not None:
         alive = ((alive != 0) & (prune != 0)).astype(alive.dtype)
@@ -435,48 +450,66 @@ def knn_fuse(
     if ecoef is None:
         ecoef = effective_coef(problem, state)
 
-    if engine == "pallas":
-        from repro.kernels.knn_fuse import knn_fuse_fused
+    from repro.kernels.knn_fuse import knn_fuse_fused
 
-        if problem.kernel.name != "rbf":
-            raise NotImplementedError(
-                "engine='pallas' fuses the RBF kernel only; use "
-                "engine='plan' for other kernels"
-            )
-        cid = query_cells(plan, xq)
-        pos_pad = jnp.concatenate([positions, jnp.zeros((1, xq.shape[1]), dt)])
-        if problem.batched:
-            nbr_pos, nbr_mask, coef = (
-                problem.nbr_pos, problem.nbr_mask, ecoef,
-            )
-        else:
-            nbr_pos = problem.nbr_pos[None]
-            nbr_mask = problem.nbr_mask[None]
-            coef = ecoef[None]
-        out = knn_fuse_fused(
-            xq, cid, plan.cells, plan.cell_mask, pos_pad,
-            nbr_pos, nbr_mask, coef,
-            alive=alive, gamma=problem.kernel.gamma, k=k,
-            block_q=block_q, compute_dtype=cdt_name,
+    if problem.kernel.name != "rbf":
+        raise NotImplementedError(
+            "engine='pallas' fuses the RBF kernel only; use "
+            "engine='plan' for other kernels"
         )
-        return out if problem.batched else out[0]
+    cid = query_cells(plan, xq)
+    pos_pad = jnp.concatenate([positions, jnp.zeros((1, xq.shape[1]), dt)])
+    if problem.batched:
+        nbr_pos, nbr_mask, coef = (
+            problem.nbr_pos, problem.nbr_mask, ecoef,
+        )
+    else:
+        nbr_pos = problem.nbr_pos[None]
+        nbr_mask = problem.nbr_mask[None]
+        coef = ecoef[None]
+    out = knn_fuse_fused(
+        xq, cid, plan.cells, plan.cell_mask, pos_pad,
+        nbr_pos, nbr_mask, coef,
+        alive=alive, gamma=problem.kernel.gamma, k=k,
+        block_q=block_q, compute_dtype=cdt_name,
+    )
+    return out if problem.batched else out[0]
 
+
+@partial(jax.jit, static_argnames=("kernel", "k", "compute_dtype"))
+def _knn_plan(
+    kernel, plan, positions, nbr_pos, nbr_mask, coef, anchor_w, alive,
+    prune, xq, k: int, compute_dtype: str | None = None,
+):
+    """The plan engine of ``knn_fuse`` as one program: (Q,) or (B, Q).
+
+    ``anchor_w`` None means ``coef`` already holds the true representer
+    coefficients (``effective_coef``); otherwise they are formed here.
+    Batched problems (a leading field axis on ``nbr_mask``) share one
+    selection across the B evaluations.
+    """
+    dt = nbr_pos.dtype
+    xq = jnp.atleast_2d(jnp.asarray(xq, dt))
+    positions = positions.astype(dt)
+    if prune is not None:
+        alive = ((alive != 0) & (prune != 0)).astype(alive.dtype)
+    if anchor_w is not None:
+        coef = coef * anchor_w.astype(coef.dtype)
     # (Q, k) shared across fields (liveness is network-level, not per-field)
     # Selection is ALWAYS full-precision — the quantized path is
     # selection-exact (see the module docstring); compute_dtype reaches
     # only the anchor-table evaluation below.
     sel, valid = knn_select_valid(plan, positions, xq, k, alive)
-    if problem.batched:
-        return jax.vmap(
-            lambda np_, nm, cf: _eval_selected(
-                problem.kernel, np_, nm, cf, sel, valid, xq, k,
-                compute_dtype=cdt_name,
-            )
-        )(problem.nbr_pos, problem.nbr_mask, ecoef)
-    return _eval_selected(
-        problem.kernel, problem.nbr_pos, problem.nbr_mask, ecoef,
-        sel, valid, xq, k, compute_dtype=cdt_name,
-    )
+
+    def one_field(np_, nm, cf):
+        return _eval_selected(
+            kernel, np_, nm, cf, sel, valid, xq, k,
+            compute_dtype=compute_dtype,
+        )
+
+    if nbr_mask.ndim == 3:
+        return jax.vmap(one_field)(nbr_pos, nbr_mask, coef)
+    return one_field(nbr_pos, nbr_mask, coef)
 
 
 # Sparsified-serving surface (ISSUE: serving.prune_plan): implemented in

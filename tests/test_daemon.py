@@ -125,6 +125,31 @@ def test_any_interleaving_drains_through_buckets(sizes):
     )
 
 
+def test_warm_pump_traces_and_compiles_nothing():
+    """One serving program per bucket: once every bucket has been pumped,
+    pumping every bucket again traces no jaxpr (``serve_traces`` grows by
+    0) and compiles nothing (the daemon group's ledger growth is 0)."""
+    prob, state, pos, _ = _fix()
+    d = Daemon(prob, state, config=DaemonConfig(k=3, max_batch_rows=64))
+    rng = np.random.default_rng(7)
+    buckets = sorted({bucket_rows(r) for r in range(1, 65)})
+
+    def pump_each_bucket():
+        for r in buckets:
+            d.submit(rng.uniform(-0.9, 0.9, size=(r, 1)).astype(np.float32))
+            (answer,) = d.pump()
+            assert answer.values.shape == (prob.y.shape[0], r)
+
+    pump_each_bucket()  # warm
+    traces = d.health()["counters"]["serve_traces"]
+    snap = compile_ledger.snapshot("daemon")
+    pump_each_bucket()
+    counters = d.health()["counters"]
+    assert counters["serve_traces"] == traces
+    assert snap.growth() == {"serving.knn_plan": 0}
+    assert counters["dispatches"] == {str(b): 2 for b in buckets}
+
+
 def test_pad_arrivals_is_bitwise_noop():
     """Absorbing a window padded with sentinel-row arrivals must equal the
     unpadded absorb bitwise — problem, state, and real-row receipt flags."""

@@ -218,15 +218,21 @@ def test_serve_traces_counts_the_pumps_jaxpr_traces(trained):
         if event == "/jax/core/compile/jaxpr_trace_duration":
             seen.append(duration_secs)
 
+    # Earlier tests compiled the pump's programs for these shapes: clear
+    # them, so this daemon's first pump has to trace its programs again.
+    jax.clear_caches()
     d = _daemon(trained)
     before = d.health()["counters"]["serve_traces"]
     jax.monitoring.register_event_duration_secs_listener(listen)
     try:
         _serve(d, _grids(2))
+        cold = len(seen)
+        _serve(d, _grids(3))  # the same buckets, now warm
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
     after = d.health()["counters"]["serve_traces"]
-    assert len(seen) > 0  # a daemon's first pump traces its programs
+    assert cold > 0  # a cold pump traces its programs
+    assert len(seen) == cold  # a warm one traces nothing
     assert after - before == len(seen)
 
 

@@ -10,13 +10,16 @@ Covers:
       evict policies, flags included);
   (d) the x64 dtype threading fix for the serving path (subprocess);
   (e) power-of-two query bucketing: a serving process with varied request
-      sizes lowers O(log Q) Pallas programs, counted via the jit cache.
+      sizes lowers O(log Q) Pallas programs, counted via the jit cache;
+  (f) the plan engine as one program answers as its eager composition
+      (selection, then a vmap of the evaluation over fields) did.
 """
 
 import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -371,3 +374,70 @@ def test_knn_engines_agree_at_liveness_fractions(live_count):
         # k exceeds the live count: predictions average the live sensors
         # only (no zero-dilution), so they are NOT scaled by live/k
         assert np.abs(dense).max() > 0.0
+
+
+# ---------------------------------------------------------------------------
+# (f) the plan engine is one program; it answers as the eager composition
+# ---------------------------------------------------------------------------
+
+
+def _eager_plan(prob, state, xq, k, plan, compute_dtype=None, prune=None):
+    """The plan engine composed eagerly: the cast, the liveness gate,
+    ``effective_coef``, the selection, then a vmap of the evaluation."""
+    from repro.core import effective_coef
+
+    cdt = serving._norm_compute_dtype(compute_dtype)
+    alive = prob.alive
+    if prune is not None:
+        alive = ((alive != 0) & (prune != 0)).astype(alive.dtype)
+    dt = prob.nbr_pos.dtype
+    xq = jnp.atleast_2d(jnp.asarray(xq, dt))
+    positions = prob.topology.positions.astype(dt)
+    ecoef = effective_coef(prob, state)
+    sel, valid = serving.knn_select_valid(plan, positions, xq, k, alive)
+
+    def one_field(np_, nm, cf):
+        return serving._eval_selected(
+            prob.kernel, np_, nm, cf, sel, valid, xq, k, compute_dtype=cdt
+        )
+
+    if prob.batched:
+        return jax.vmap(one_field)(prob.nbr_pos, prob.nbr_mask, ecoef)
+    return one_field(prob.nbr_pos, prob.nbr_mask, ecoef)
+
+
+@pytest.mark.parametrize("case", ["batched", "single", "bf16_prune", "churned"])
+def test_plan_engine_program_matches_eager_composition(case):
+    from repro.core import pruning, remove_sensor
+
+    k, cdt, prune = 3, None, None
+    if case == "single":
+        prob, state, pos, rng = _single(n=30, seed=4)
+    else:
+        prob, state, pos, rng = _batched(n=30, seed=4)
+    plan = make_serving_plan(prob, k=k, slack=4)
+    if case == "bf16_prune":
+        cdt = "bf16"
+        energy = np.asarray(pruning.representer_energy(prob, state))
+        tau = float(np.median(energy[: prob.n]))
+        prune = pruning.prune_mask(prob, state, energy_tau=tau)
+        assert 0 < int(np.asarray(prune[: prob.n]).sum()) < prob.n
+    if case == "churned":
+        for s in (3, 11, 20):
+            prob, state, ok = remove_sensor(prob, state, s)
+            assert bool(ok)
+            plan = serving.plan_remove_sensor(plan, s)
+        assert int(np.asarray(prob.alive[: prob.n]).sum()) == prob.n - 3
+    xq = rng.uniform(-0.9, 0.9, size=(37, 1)).astype(np.float32)
+    got = np.asarray(fusion.fuse(
+        prob, state, xq, "knn", k=k, engine="plan", plan=plan,
+        compute_dtype=cdt, prune=prune,
+    ))
+    want = np.asarray(_eager_plan(prob, state, xq, k, plan, cdt, prune))
+    assert got.shape == want.shape == (
+        (prob.y.shape[0], 37) if prob.batched else (37,)
+    )
+    np.testing.assert_array_equal(got, want)
+    if prune is None:  # the dense oracle knows no prune mask
+        dense = np.asarray(fusion.fuse(prob, state, xq, "knn", k=k))
+        np.testing.assert_allclose(got, dense, atol=1e-5)
