@@ -12,7 +12,11 @@ gives for
     anchor tables, ``serve_dtype="bf16"``); for the converge cells, the
     reference itself computed with three-pass (``--controls bf16x3``) or
     one-pass (``bf16``) bfloat16 matrix products in the program's place,
-    at the sweep count the program's solve took;
+    at the sweep count the program's solve took; for the daemon's sweeps
+    (open loop), the reference at each of ``--controls`` put in the
+    program's place beside the program's own reading of a control seed:
+    the same set-up sweeps, absorbs and tick sweeps, its messages against
+    the reference's at the snapshot the check compares;
   * a planted fault (``--fault early_stop --fault-seeds 1-3``): the
     program with a fault of ``faults.py`` under it, read last.
 
@@ -91,9 +95,46 @@ def solve_readings(net, cfg: dict, seed: int, controls=()) -> dict:
     return out
 
 
+def shadowed(precisions) -> type:
+    """``reference.Reference`` with a copy at each of ``precisions`` fed
+    the same sweeps and absorbs; at each ``messages()`` call (the check
+    calls it for the snapshot it compares) ``gaps`` keeps each copy's
+    message gap against it: the control of the daemon's sweeps."""
+    import drivers
+    import reference
+
+    class Shadowed(reference.Reference):
+        gaps: dict = {}
+
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            self.shadows = {p: reference.Reference(*a, **{**k, "precision": p})
+                            for p in precisions}
+
+        def sweeps(self, count):
+            super().sweeps(count)
+            for s in self.shadows.values():
+                s.sweeps(count)
+
+        def absorb(self, *a):
+            for s in self.shadows.values():
+                s.absorb(*a)
+            return super().absorb(*a)
+
+        def messages(self):
+            m = super().messages()
+            Shadowed.gaps = {p: drivers._gap(s.messages(), m)
+                             for p, s in self.shadows.items()}
+            return m
+
+    return Shadowed
+
+
 def run_readings(cell: str, net, seed: int, seconds: float, daemon: dict,
-                 mix: dict | None = None) -> dict:
-    """The comparison's numbers for one short run of a serving cell."""
+                 mix: dict | None = None, controls=()) -> dict:
+    """The comparison's numbers for one short run of a serving cell, and
+    with ``controls`` those of the reference at each precision in the
+    place of the daemon's sweeps (``control_<precision>``)."""
     import drivers
     import harness
 
@@ -105,9 +146,19 @@ def run_readings(cell: str, net, seed: int, seconds: float, daemon: dict,
                           seconds=seconds, t_start=time.perf_counter(), net=net,
                           daemon=daemon)
     rec, check = drivers.DRIVERS[ctx.mix["loop"]](ctx)
-    out = check()
-    return {"numbers": out, "attempted": rec.attempted, "failed": rec.failed,
-            "notes": rec.notes}
+    plain = drivers.Reference
+    if controls:
+        drivers.Reference = shadowed(controls)
+    try:
+        out = check()
+    finally:
+        gaps = getattr(drivers.Reference, "gaps", {})
+        drivers.Reference = plain
+    r = {"numbers": out, "attempted": rec.attempted, "failed": rec.failed,
+         "notes": rec.notes}
+    for p, gap in gaps.items():
+        r["control_" + p] = {"message_gap": gap}
+    return r
 
 
 def main(argv=None) -> int:
@@ -116,7 +167,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", default="1-12")
     ap.add_argument("--control-seeds", default="1-3")
     ap.add_argument("--controls", default="bf16x3",
-                    help="converge cells: control precisions, comma-separated")
+                    help="converge and open-loop cells: control precisions of the "
+                    "sweeps, comma-separated")
     ap.add_argument("--fault", default="", help="a fault of faults.py to plant "
                     "after the program and control readings")
     ap.add_argument("--fault-seeds", default="")
@@ -153,7 +205,9 @@ def main(argv=None) -> int:
                                else ())
         else:
             r = run_readings(args.workload, net, seed, args.seconds, overrides,
-                             json.loads(args.mix))
+                             json.loads(args.mix),
+                             controls=controls if kind == "program" and loop == "open"
+                             and seed in control_seeds else ())
         r.update(seed=seed, kind=kind, seconds=time.perf_counter() - t)
         print(json.dumps(r), flush=True)
 

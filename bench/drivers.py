@@ -39,20 +39,18 @@ class Context:
 
 
 class Window:
-    """The measured window; traces its first ``trace_s`` seconds."""
+    """The measured window; traces ``trace_s`` seconds of it, from
+    ``trace_from`` seconds after it opens."""
 
-    def __init__(self, ctx: Context, spans: Spans):
+    def __init__(self, ctx: Context, spans: Spans, trace_from: float = 0.0):
         self.ctx, self.spans = ctx, spans
-        self.tracing = False
+        self.trace_from = trace_from
+        self.tracing = self.traced = False
         self.trace_t0 = self.trace_t1 = 0.0
 
     def open(self) -> float:
-        if self.ctx.trace_s > 0:
-            import jax
-
-            jax.profiler.start_trace(self.ctx.trace_dir)
-            self.spans.annotate = True
-            self.tracing = True
+        if self.ctx.trace_s > 0 and self.trace_from <= 0:
+            self.start()
         self.compiles_at_open = compile_count()
         print("bench: window open", file=sys.stderr, flush=True)
         self.t0 = time.perf_counter()
@@ -60,10 +58,16 @@ class Window:
         return self.t0
 
     def poll(self) -> float:
-        """Seconds since the window opened; ends the trace when due."""
+        """Seconds since the window opened; starts and ends the trace
+        when due."""
         now = time.perf_counter()
-        if self.tracing and now - self.t0 >= self.ctx.trace_s:
+        if self.tracing and now - self.trace_t0 >= self.ctx.trace_s:
             self.stop()
+        elif self.ctx.trace_s > 0 and not self.traced and now - self.t0 >= self.trace_from:
+            self.start()
+            self.trace_t0 = time.perf_counter()
+            print(f"bench: trace from {self.trace_t0 - self.t0:.3f} s "
+                  f"(start took {self.trace_t0 - now:.3f} s)", file=sys.stderr, flush=True)
         return now - self.t0
 
     def close(self, rec: Record) -> None:
@@ -73,6 +77,13 @@ class Window:
         self.compiles_in_window = compile_count() - self.compiles_at_open
         print("bench: window closed", file=sys.stderr, flush=True)
         self.stop()
+
+    def start(self) -> None:
+        import jax
+
+        jax.profiler.start_trace(self.ctx.trace_dir)
+        self.spans.annotate = True
+        self.tracing = self.traced = True
 
     def stop(self) -> None:
         if self.tracing:
@@ -214,8 +225,14 @@ def open_loop(ctx: Context):
     _warm_daemon(Daemon, prob, state, dcfg, plan, net, box)
     d = Daemon(prob, state, config=dcfg, plan=plan)
     del prob, state
-    req = traffic.open_requests(mix, ctx.seconds, rng_req, box)
-    arr = traffic.reports(cfg, net.pos, fields, ctx.seconds, rng_arr)
+    # a traced run traces the settled loop, after the first third of the
+    # window (the climb from an empty queue), and offers traffic until the
+    # trace ends: the profiler's stop takes tens of seconds, and requests
+    # falling due behind it would fill the queue and shed
+    trace_from = ctx.seconds / 3 if ctx.trace_s else 0.0
+    seconds = trace_from + ctx.trace_s if ctx.trace_s else ctx.seconds
+    req = traffic.open_requests(mix, seconds, rng_req, box)
+    arr = traffic.reports(cfg, net.pos, fields, seconds, rng_arr)
     n_req, n_arr = len(req.due), len(arr.due)
     checked = set(rng_check.choice(n_req, size=min(mix["check_requests"], n_req),
                                    replace=False).tolist())
@@ -228,7 +245,7 @@ def open_loop(ctx: Context):
     pending: list = []  # arrivals absorbed but not yet published
     id_of = {}  # daemon query id -> request index
 
-    window = Window(ctx, spans)
+    window = Window(ctx, spans, trace_from)
     rec.setup_s = time.perf_counter() - ctx.t_start
     t0 = window.open()
     i_req = i_arr = 0
@@ -281,7 +298,7 @@ def open_loop(ctx: Context):
 
     while True:
         el = window.poll()
-        if el >= ctx.seconds:
+        if el >= seconds:
             break
         first = release(el)
         serve_and_train()
@@ -289,7 +306,7 @@ def open_loop(ctx: Context):
     window.close(rec)
     # drain: everything due in the window is released, answered and
     # published, late but counted
-    first = release(ctx.seconds)
+    first = release(seconds)
     serve_and_train()
     tick(first)
     close = time.perf_counter()

@@ -1,9 +1,9 @@
 """The control comes out not correct at a size a test run holds.
 
-Converge cells: the reference computed with three-pass bfloat16 matrix
-products in the program's place.  Serving cells: the program with its
-own bfloat16 anchor tables switched on.  (On the chip at the cells' own
-sizes: ``calibrate.py``; readings in PERF.md.)
+Converge cells, and the daemon's sweeps: the reference computed with
+three-pass bfloat16 matrix products in the program's place.  Serving
+cells: the program with its own bfloat16 anchor tables switched on.  (On
+the chip at the cells' own sizes: ``calibrate.py``; readings in PERF.md.)
 """
 
 import time
@@ -12,6 +12,7 @@ import pytest
 
 import calibrate
 import deploy
+import drivers
 import harness
 from tinycfg import tiny
 
@@ -37,3 +38,29 @@ def test_serving_control_is_not_correct(cell):
     out = harness.run(cell, 2**35 + 2, 1.0, False, time.perf_counter(), spec=spec,
                       cfg=cfg, daemon={"serve_dtype": "bf16"})
     assert not out["correct"], out["checks"]
+
+
+class _Steps(drivers.Window):
+    """A window whose clock moves 10 ms per loop iteration: a 3 s window
+    runs 300 ticks however busy the machine is."""
+
+    n = 0
+
+    def poll(self) -> float:
+        self.n += 1
+        return 0.01 * self.n
+
+
+def test_daemon_sweep_control_fails_message_gap(monkeypatch):
+    spec, cfg = tiny("city2k-daemon")
+    mix = spec.traffic(spec.cell("city2k-daemon")["traffic"])
+    # 300 ticks: enough sweeps on the tiny network for the three-pass
+    # rounding to build up as over the cell's set-up solve
+    monkeypatch.setattr(drivers, "Window", _Steps)
+    ctx = drivers.Context(cfg=cfg, mix=mix, seed=2**35 + 4, seconds=3.0,
+                          t_start=time.perf_counter())
+    _, check = drivers.open_loop(ctx)
+    monkeypatch.setattr(drivers, "Reference", calibrate.shadowed(("bf16x3",)))
+    limit = spec.limits("city2k-daemon")["message_gap"]
+    assert check()["message_gap"] <= limit
+    assert drivers.Reference.gaps["bf16x3"] > limit

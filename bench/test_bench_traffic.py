@@ -21,11 +21,21 @@ def test_same_seed_same_schedule():
     assert np.array_equal(a.due, b.due) and a.kinds == b.kinds
     assert all(np.array_equal(x, y) for x, y in zip(a.queries, b.queries))
     c = traffic.open_requests(MIX, 10.0, deploy.streams(8, 1)[0], BOX)
-    assert not np.array_equal(a.due, c.due)
-    # every seed gets the same amount of work
-    assert len(c.due) == len(a.due) == 400
+    # every seed gets the same work at the same times, at other points
+    assert np.array_equal(a.due, c.due) and a.kinds == c.kinds and len(c.due) == 400
+    assert [len(q) for q in a.queries] == [len(q) for q in c.queries]
     assert sorted(len(q) for q in c.queries)[-80:] == [256] * 80
-    assert sum(len(q) for q in a.queries) == sum(len(q) for q in c.queries)
+    assert not all(np.array_equal(x, y) for x, y in zip(a.queries, c.queries))
+
+
+def test_seeds_share_the_arrival_times_not_the_sensors():
+    spec, _ = tiny("city2k-daemon")
+    cfg = spec.config("city-aq-2k")
+    pos = deploy.positions(cfg)
+    fields = deploy.Fields(cfg["fields"], np.random.default_rng(0), cfg["noise"])
+    x, y = (traffic.reports(cfg, pos, fields, 30.0, np.random.default_rng(s))
+            for s in (5, 6))
+    assert np.array_equal(x.due, y.due) and not np.array_equal(x.sensors, y.sensors)
 
 
 def test_city_arrivals_never_repeat_a_pair():
@@ -91,3 +101,16 @@ def test_closed_loop_keeps_clients_outstanding(monkeypatch):
     assert len(queued) > 5
     assert all(q == clients for q in queued[:-1])  # the last pump drains after close
     assert rec.notes["requests"] >= clients * (len(queued) - 2)
+
+
+def test_traced_open_loop_offers_its_traced_segment_alone(monkeypatch, tmp_path):
+    ctx = _ctx("city2k-daemon", 3.0, rate_per_s=40.0)
+    ctx.trace_s, ctx.trace_dir = 1.0, str(tmp_path)
+    monkeypatch.setattr(drivers, "_warm_daemon", lambda *a: None)
+    rec, _ = drivers.open_loop(ctx)
+    # the trace covers the settled loop, after the window's first third
+    w = rec.window
+    assert w.trace_t0 - w.t0 >= 1.0 and w.trace_t1 > w.trace_t0
+    # the profiler's stop sheds nothing: traffic ends with the trace, so
+    # no request falls due behind it
+    assert rec.notes["requests"] == 80 and rec.failed == 0

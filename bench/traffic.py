@@ -11,10 +11,13 @@ harness drives it:
               sending the next request;
   ``solve``   repeated solves of all fields from the initial state.
 
-Every seed gets the same amount of work: the number of requests in a
-window is fixed by the rate and the window, the request sizes are a fixed
-multiset (shuffled by the seed), and due times are uniform order
-statistics (a Poisson process given its count).
+Every seed gets the same work: the number of requests in a window is
+fixed by the rate and the window, the request sizes are a fixed multiset,
+and one fixed generator (``SCHEDULE_SEED``) draws their order and the due
+times of an open mix's requests and arrivals (uniform order statistics: a
+Poisson process given its count).  So every seed offers the same sizes at
+the same times, and ``--seed`` draws where the queries fall, which sensors
+report and what they read.
 """
 
 from __future__ import annotations
@@ -66,12 +69,22 @@ def _points(kind: dict, rng, box, rows: int | None = None) -> np.ndarray:
     raise ValueError(f"unknown request kind {kind['kind']!r}")
 
 
+SCHEDULE_SEED = 1  # the open mixes' timing, the same for every --seed
+
+
+def _schedules() -> tuple:
+    """Generators of the requests' and the arrivals' timing."""
+    seq = np.random.SeedSequence(SCHEDULE_SEED)
+    return tuple(np.random.default_rng(s) for s in seq.spawn(2))
+
+
 def open_requests(mix: dict, seconds: float, rng, box) -> Requests:
     count = int(round(mix["rate_per_s"] * seconds))
     kinds = _sizes(mix["requests"], count)
-    order = rng.permutation(count)
+    sched = _schedules()[0]
+    order = sched.permutation(count)
     kinds = [kinds[i] for i in order]
-    due = np.sort(rng.uniform(0.0, seconds, size=count))
+    due = np.sort(sched.uniform(0.0, seconds, size=count))
     # point requests cycle through every size from rows_min to rows_max,
     # so the rows of a window are the same for every seed
     cycle = {}
@@ -92,7 +105,8 @@ def reports(cfg: dict, net_pos: np.ndarray, fields, seconds: float, rng) -> Arri
 
     The window sees ``sensors * seconds / interval`` reports, from
     distinct sensors while the window is shorter than the interval, so
-    no (field, sensor) pair repeats within a run.
+    no (field, sensor) pair repeats within a run.  The due times come from
+    ``SCHEDULE_SEED``, all else from ``rng``.
     """
     n = net_pos.shape[0]
     interval = float(cfg["report_interval_s"])
@@ -100,7 +114,7 @@ def reports(cfg: dict, net_pos: np.ndarray, fields, seconds: float, rng) -> Arri
         raise ValueError("a window longer than the reporting interval repeats sensors")
     reporters = int(round(n * seconds / interval))
     sensors = rng.choice(n, size=reporters, replace=False)
-    due = np.sort(rng.uniform(0.0, seconds, size=reporters))
+    due = np.sort(_schedules()[1].uniform(0.0, seconds, size=reporters))
     q = len(cfg["quantities"])
     newest = [i * cfg["intervals"] + cfg["intervals"] - 1 for i in range(q)]
     f = np.tile(np.asarray(newest), reporters)
