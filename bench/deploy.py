@@ -5,6 +5,26 @@ every run of a configuration has the same topology, the same padded
 shapes and the same compiled programs; ``--seed`` draws the field values,
 the traffic and the sample that is checked.  The field values are made on
 the host: at most B x n floats (2736 x 54 or 64 x 2000).
+
+The configuration's ``placement`` says how the ``sensors`` positions are
+drawn on the square ``domain`` ([lo, hi] on each of ``dim`` axes):
+
+  ``"uniform"``  uniform on the domain.
+  ``{"kind": "clustered", "centres": C, "zipf_s": s, "sigma": sigma,
+  "background": beta}``  a Thomas (Neyman-Scott) cluster process, as
+      sensors placed where people live:
+      ``centres``     C >= 1 cluster centres, uniform on the domain;
+      ``zipf_s``      s >= 0: the centre of rank r (1..C, in the order they
+                      are drawn) takes a share of the clustered sensors
+                      proportional to 1 / r**s (Zipf's law of city sizes);
+      ``sigma``       sigma > 0: a clustered sensor lies at its centre plus
+                      a Gaussian offset of this standard deviation per axis;
+      ``background``  0 <= beta <= 1: each sensor is uniform on the domain
+                      with this probability, else clustered.
+      A clustered draw that falls outside the domain is drawn again around
+      the same centre, so there are exactly ``sensors`` positions and the
+      background share is binomial.
+Any other value fails at set-up, naming the key.
 """
 
 from __future__ import annotations
@@ -19,10 +39,45 @@ def radius(cfg: dict) -> float:
     return cfg["radius_scale"] * math.sqrt(100.0 / cfg["sensors"])
 
 
-def positions(cfg: dict) -> np.ndarray:
+CLUSTERED = ("kind", "centres", "zipf_s", "sigma", "background")
+
+
+def layout(cfg: dict) -> tuple:
+    """(positions (n, d) float32, centre (n,) int): the centre each sensor
+    was drawn around, -1 for a uniform (background) sensor."""
     lo, hi = cfg["domain"]
+    n, d = cfg["sensors"], cfg["dim"]
     rng = np.random.default_rng(cfg["placement_seed"])
-    return rng.uniform(lo, hi, size=(cfg["sensors"], cfg["dim"])).astype(np.float32)
+    place = cfg["placement"]
+    if place == "uniform":
+        pos = rng.uniform(lo, hi, size=(n, d)).astype(np.float32)
+        return pos, np.full(n, -1)
+    if not isinstance(place, dict) or place.get("kind") != "clustered":
+        raise ValueError(f"placement: unknown placement {place!r}; "
+                         "'uniform' or {'kind': 'clustered', ...}")
+    odd = sorted(set(place) ^ set(CLUSTERED))
+    if odd:
+        raise ValueError(f"placement: keys {odd} missing or unknown; a clustered "
+                         f"placement has exactly {list(CLUSTERED)}")
+    c, s = int(place["centres"]), float(place["zipf_s"])
+    sigma, beta = float(place["sigma"]), float(place["background"])
+    for key, bad in (("centres", c < 1 or c != place["centres"]), ("zipf_s", s < 0),
+                     ("sigma", not sigma > 0), ("background", not 0 <= beta <= 1)):
+        if bad:
+            raise ValueError(f"placement.{key}: {place[key]!r} is out of range")
+    centres = rng.uniform(lo, hi, size=(c, d))
+    weight = 1.0 / np.arange(1, c + 1) ** s
+    centre = np.where(rng.random(n) < beta, -1, rng.choice(c, size=n, p=weight / weight.sum()))
+    pos = rng.uniform(lo, hi, size=(n, d))
+    todo = np.nonzero(centre >= 0)[0]
+    while len(todo):
+        pos[todo] = centres[centre[todo]] + sigma * rng.normal(size=(len(todo), d))
+        todo = todo[np.any((pos[todo] < lo) | (pos[todo] > hi), axis=1)]
+    return pos.astype(np.float32), centre
+
+
+def positions(cfg: dict) -> np.ndarray:
+    return layout(cfg)[0]
 
 
 def streams(seed: int, n: int) -> list:

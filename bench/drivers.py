@@ -231,7 +231,7 @@ def open_loop(ctx: Context):
     # falling due behind it would fill the queue and shed
     trace_from = ctx.seconds / 3 if ctx.trace_s else 0.0
     seconds = trace_from + ctx.trace_s if ctx.trace_s else ctx.seconds
-    req = traffic.open_requests(mix, seconds, rng_req, box)
+    req = traffic.open_requests(mix, seconds, rng_req, box, net.pos)
     arr = traffic.reports(cfg, net.pos, fields, seconds, rng_arr)
     n_req, n_arr = len(req.due), len(arr.due)
     checked = set(rng_check.choice(n_req, size=min(mix["check_requests"], n_req),
@@ -375,6 +375,7 @@ def open_loop(ctx: Context):
         }
         rec.notes["answers_compared"] = len(kept) - unmatched
         rec.notes["near_ties_skipped"] = ties
+        rec.notes["reference_blocks"] = len(ref.blocks)
         return out
 
     lanes = _lanes(net)
@@ -415,7 +416,7 @@ def closed_loop(ctx: Context):
     t0 = window.open()
 
     def send(now):
-        xq = traffic.closed_request(mix, rng_req, box)
+        xq = traffic.closed_request(mix, rng_req, box, net.pos)
         tk = d.submit(xq, now=now)
         if not tk.admitted:
             raise RuntimeError("a closed-loop request was shed")
@@ -468,6 +469,7 @@ def closed_loop(ctx: Context):
         keep = ~tie
         rec.notes["answers_compared"] = len(kept)
         rec.notes["near_ties_skipped"] = int(tie.sum())
+        rec.notes["reference_blocks"] = len(ref.blocks)
         return {"answer_gap": _gap(got[:, keep], want[:, keep])}
 
     lanes = _lanes(net)

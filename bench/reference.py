@@ -33,6 +33,14 @@ coefficients product as three bfloat16 passes (what a TPU matrix unit
 does at ``precision="high"``): the control, one step below it.
 ``"bf16"`` is one bfloat16 pass (the TPU's default precision), a step
 further down.
+
+Memory: fields are independent, so the per-field state (messages,
+coefficients, absorbed anchors, and the (n+1, L, L) Gram and Cholesky
+factors of every field, which dominate) is held in blocks of fields.
+Where the whole state fits in ``BUDGET_SHARE`` of the device's memory it
+is one block, always on the device.  Otherwise each block lives on the
+host and is moved to the device for each call, one block at a time, and
+the calls' results are concatenated over the blocks.
 """
 
 from __future__ import annotations
@@ -43,6 +51,24 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+
+BUDGET_SHARE = 0.25
+"""Share of the device's memory (its ``bytes_limit``) that one reference's
+per-field state may take.  A quarter leaves room for what a call makes
+beside it: an absorb's updated Gram and Cholesky factors are new arrays
+while the old ones live (2x those), a sweep's per-class gathers of them,
+an answer's anchor gathers, and the shadows ``calibrate.py`` feeds the
+same calls, each a reference of its own."""
+
+
+def state_budget() -> float:
+    """Bytes of per-field state that one block may hold: ``BUDGET_SHARE``
+    of the device's memory, or no limit where the platform reports none
+    (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return BUDGET_SHARE * limit if limit else float("inf")
 
 
 def adjacency(pos: np.ndarray, radius: float) -> np.ndarray:
@@ -195,14 +221,43 @@ def _answer_block(xq, pos, nbr, nmask, pp, pmask, coef, gamma, k):
     return jnp.mean(f, axis=-1), tie
 
 
+class _Block:
+    """The per-field state of fields ``lo`` .. ``hi`` - 1, on the device or
+    parked on the host."""
+
+    STATE = ("z", "zp", "coef", "pp", "pmask", "gram", "chol")
+    FACTORS = ("pp", "pmask", "gram", "chol")  # changed by absorbs alone
+
+    def __init__(self, lo: int, hi: int, **state):
+        self.lo, self.hi = lo, hi
+        self.__dict__.update(state)
+        self.host = None  # the parked factors, while they are unchanged on the device
+
+    def load(self) -> None:
+        """To the device; the factors' host copies are kept."""
+        self.host = {k: getattr(self, k) for k in self.FACTORS}
+        for k in self.STATE:
+            setattr(self, k, jnp.asarray(getattr(self, k)))
+
+    def park(self, absorbed: bool) -> None:
+        """To the host, downloading the factors only where an absorb changed
+        them."""
+        for k in self.STATE:
+            keep = k in self.FACTORS and not absorbed
+            setattr(self, k, self.host[k] if keep else np.array(getattr(self, k)))
+        self.host = None
+
+
 class Reference:
     """Reference state for B fields over one network.
 
     pos (n, d), ys (B, n) readings, ``lanes`` private anchor lanes per
-    (field, sensor) for absorbed measurements.
+    (field, sensor) for absorbed measurements.  ``budget``: bytes of
+    per-field state one block may hold (default ``state_budget()``).
     """
 
-    def __init__(self, pos, radius, gamma, lam, ys, lanes=0, precision="highest"):
+    def __init__(self, pos, radius, gamma, lam, ys, lanes=0, precision="highest",
+                 budget=None):
         pos = np.asarray(pos, np.float32)
         n, d = pos.shape
         adj = adjacency(pos, radius)
@@ -233,25 +288,55 @@ class Reference:
         self.nmask = jnp.asarray(nmask)
         self.lam = jnp.full((n + 1,), lam, jnp.float32)
         self.classes = jnp.asarray(classes)
-        self.pp = jnp.asarray(self.pp_np)
-        self.pmask = jnp.asarray(self.pmask_np)
-        self.z = jnp.asarray(np.concatenate([ys, np.zeros((b, 1), np.float32)], 1))
-        self.zp = jnp.zeros((b, n + 1, p), jnp.float32)
-        self.coef = jnp.zeros((b, n + 1, dn + p), jnp.float32)
         # before any absorb every field has the same factors
         anchors = np.concatenate([self.pos1[nbr], np.zeros((n + 1, p, d), np.float32)], 1)
         mask = np.concatenate([nmask, np.zeros((n + 1, p), bool)], axis=1)
         gram, chol = factors(anchors, mask, np.full(n + 1, lam), self.gamma)
-        self.gram = jnp.broadcast_to(jnp.asarray(gram), (b,) + gram.shape)
-        self.chol = jnp.broadcast_to(jnp.asarray(chol), (b,) + chol.shape)
+        z = np.concatenate([ys, np.zeros((b, 1), np.float32)], 1)
+        lanes_all = dn + p
+        # bytes of one field's z, zp, coef, pp, gram, chol (float32), pmask
+        self.field_bytes = (n + 1) * (4 * (1 + p + lanes_all * (1 + 2 * lanes_all) + p * d) + p)
+        budget = state_budget() if budget is None else budget
+        size = int(max(1, min(b, budget // self.field_bytes)))
+        self.blocks = []
+        for lo in range(0, b, size):
+            hi = min(lo + size, b)
+            if size == b:  # one block: on the device throughout
+                state = dict(
+                    z=jnp.asarray(z), zp=jnp.zeros((b, n + 1, p), jnp.float32),
+                    coef=jnp.zeros((b, n + 1, lanes_all), jnp.float32),
+                    pp=jnp.asarray(self.pp_np), pmask=jnp.asarray(self.pmask_np),
+                    gram=jnp.broadcast_to(jnp.asarray(gram), (b,) + gram.shape),
+                    chol=jnp.broadcast_to(jnp.asarray(chol), (b,) + chol.shape))
+            else:
+                f = hi - lo
+                state = dict(
+                    z=z[lo:hi], zp=np.zeros((f, n + 1, p), np.float32),
+                    coef=np.zeros((f, n + 1, lanes_all), np.float32),
+                    pp=self.pp_np[lo:hi].copy(), pmask=self.pmask_np[lo:hi].copy(),
+                    gram=np.broadcast_to(gram, (f,) + gram.shape),
+                    chol=np.broadcast_to(chol, (f,) + chol.shape))
+            self.blocks.append(_Block(lo, hi, **state))
+
+    def _each(self, blocks=None, absorbed: bool = False):
+        """Each block (of ``blocks``, default all) on the device in turn;
+        parked again after it, where there are several."""
+        for blk in self.blocks if blocks is None else blocks:
+            if len(self.blocks) == 1:
+                yield blk
+                continue
+            blk.load()
+            yield blk
+            blk.park(absorbed)
 
     def sweeps(self, count: int) -> None:
         if count <= 0:
             return
-        self.z, self.zp, self.coef = _sweeps(
-            self.z, self.zp, self.coef, self.nbr, self.nmask, self.pmask,
-            self.lam, self.gram, self.chol, self.classes, count, self.precision,
-        )
+        for blk in self._each():
+            blk.z, blk.zp, blk.coef = _sweeps(
+                blk.z, blk.zp, blk.coef, self.nbr, self.nmask, blk.pmask,
+                self.lam, blk.gram, blk.chol, self.classes, count, self.precision,
+            )
 
     def absorb(self, fields, sensors, xs, ys) -> int:
         """Give each (field, sensor) a new anchor; returns how many fit."""
@@ -269,44 +354,53 @@ class Reference:
         anchors = np.concatenate([self.pos1[self.nbr_np[s]], self.pp_np[f, s]], axis=1)
         mask = np.concatenate([self.nmask_np[s], self.pmask_np[f, s]], axis=1)
         gram, chol = factors(anchors, mask, np.full(len(s), self.lam_f), self.gamma)
-        f, s, lane = jnp.asarray(f), jnp.asarray(s), jnp.asarray(lane)
-        self.pp = jnp.asarray(self.pp_np)
-        self.pmask = jnp.asarray(self.pmask_np)
-        self.zp = self.zp.at[f, s, lane].set(jnp.asarray(ys[keep]))
-        self.coef = self.coef.at[f, s, self.dn + lane].set(0.0)
-        self.gram = self.gram.at[f, s].set(jnp.asarray(gram))
-        self.chol = self.chol.at[f, s].set(jnp.asarray(chol))
+        val = ys[keep]
+        touched = [blk for blk in self.blocks if np.any((f >= blk.lo) & (f < blk.hi))]
+        for blk in self._each(touched, absorbed=True):
+            mine = (f >= blk.lo) & (f < blk.hi)
+            fb, sb, lb = (jnp.asarray(v[mine]) for v in (f - blk.lo, s, lane))
+            blk.pp = jnp.asarray(self.pp_np[blk.lo:blk.hi])
+            blk.pmask = jnp.asarray(self.pmask_np[blk.lo:blk.hi])
+            blk.zp = blk.zp.at[fb, sb, lb].set(jnp.asarray(val[mine]))
+            blk.coef = blk.coef.at[fb, sb, self.dn + lb].set(0.0)
+            blk.gram = blk.gram.at[fb, sb].set(jnp.asarray(gram[mine]))
+            blk.chol = blk.chol.at[fb, sb].set(jnp.asarray(chol[mine]))
         return len(rows)
 
     def answer(self, xq: np.ndarray, k: int):
         """(B, Q) answers and (Q,) near-tie flags at query points xq."""
         xq = np.asarray(xq, np.float32)
-        lanes = self.dn + self.pp.shape[2]
-        block = int(max(8, min(1024, 2**22 // max(1, self.b * k * lanes * self.d))))
-        outs, ties = [], []
-        for i in range(0, len(xq), block):
-            xb = xq[i:i + block]
-            rows = len(xb)
-            if rows < block:  # one compiled shape per block size
-                xb = np.concatenate([xb, np.repeat(xb[-1:], block - rows, 0)])
-            o, t = _answer_block(
-                jnp.asarray(xb), self.pos[:-1], self.nbr, self.nmask,
-                self.pp, self.pmask, self.coef, self.gamma, k,
-            )
-            outs.append(np.asarray(o)[:, :rows])
-            ties.append(np.asarray(t)[:rows])
-        return np.concatenate(outs, 1), np.concatenate(ties)
+        outs = []
+        for blk in self._each():
+            lanes = self.dn + blk.pp.shape[2]
+            b = blk.hi - blk.lo
+            block = int(max(8, min(1024, 2**22 // max(1, b * k * lanes * self.d))))
+            out, ties = [], []
+            for i in range(0, len(xq), block):
+                xb = xq[i:i + block]
+                rows = len(xb)
+                if rows < block:  # one compiled shape per block size
+                    xb = np.concatenate([xb, np.repeat(xb[-1:], block - rows, 0)])
+                o, t = _answer_block(
+                    jnp.asarray(xb), self.pos[:-1], self.nbr, self.nmask,
+                    blk.pp, blk.pmask, blk.coef, self.gamma, k,
+                )
+                out.append(np.asarray(o)[:, :rows])
+                ties.append(np.asarray(t)[:rows])
+            outs.append(np.concatenate(out, 1))
+        return np.concatenate(outs, 0), np.concatenate(ties)  # ties: any block's
 
     def messages(self) -> np.ndarray:
         """(B, n) messages of the sensors' own slots."""
-        return np.asarray(self.z[:, : self.n])
+        return np.concatenate([np.asarray(blk.z[:, : self.n]) for blk in self._each()])
 
     def slots(self) -> np.ndarray:
         """(B, n + n P) every live message slot: the sensors' own and the
         absorbed measurements'."""
-        zp = np.asarray(self.zp[:, : self.n]).reshape(self.b, -1)
-        return np.concatenate([self.messages(), zp], axis=1)
+        zp = np.concatenate([np.asarray(blk.zp[:, : self.n]) for blk in self._each()])
+        return np.concatenate([self.messages(), zp.reshape(self.b, -1)], axis=1)
 
     def coefficients(self) -> np.ndarray:
         """(B, n, D) coefficients of the neighbor lanes, neighbors ascending."""
-        return np.asarray(self.coef[:, : self.n, : self.dn])
+        return np.concatenate([np.asarray(blk.coef[:, : self.n, : self.dn])
+                               for blk in self._each()])

@@ -11,6 +11,14 @@ harness drives it:
               sending the next request;
   ``solve``   repeated solves of all fields from the initial state.
 
+A request kind (``points`` or ``tile``) draws its points uniform on the
+query box, or, with ``"near": "sensors"`` and ``"jitter": j``, around the
+sensors, as people ask where they and the sensors are: each point (a
+tile's centre) is a sensor drawn from the request generator plus Gaussian
+noise of ``j`` x the box's side per axis, clipped into the box (a tile is
+shifted to lie inside it).  The box is ``deploy.query_box``, the serving
+plan's exactness contract.
+
 Every seed gets the same work: the number of requests in a window is
 fixed by the rate and the window, the request sizes are a fixed multiset,
 and one fixed generator (``SCHEDULE_SEED``) draws their order and the due
@@ -52,17 +60,35 @@ def _sizes(kinds: list, count: int) -> list:
     return out
 
 
-def _points(kind: dict, rng, box, rows: int | None = None) -> np.ndarray:
+def _near(kind: dict, rng, box, pos, count: int) -> np.ndarray:
+    """``count`` points around sensors drawn from ``rng``, inside ``box``."""
+    if kind["near"] != "sensors":
+        raise ValueError(f"request kind {kind['kind']!r}: near {kind['near']!r}; "
+                         "only 'sensors' is known")
+    if pos is None:
+        raise ValueError("requests near sensors need the sensors' positions")
+    lo, hi = box
+    x = pos[rng.integers(0, len(pos), size=count)]
+    x = x + float(kind["jitter"]) * (hi - lo) * rng.normal(size=x.shape)
+    return np.clip(x, lo, hi)
+
+
+def _points(kind: dict, rng, box, rows: int | None = None, pos=None) -> np.ndarray:
     lo, hi = box
     d = len(lo)
     if kind["kind"] == "points":
         if rows is None:
             rows = int(rng.integers(kind["rows_min"], kind["rows_max"] + 1))
+        if "near" in kind:
+            return _near(kind, rng, box, pos, rows).astype(np.float32)
         return rng.uniform(lo, hi, size=(rows, d)).astype(np.float32)
     if kind["kind"] == "tile":
         g = int(kind["grid"])
         side = float(kind["tile_side"]) * (hi - lo)
-        corner = rng.uniform(lo, hi - side)
+        if "near" in kind:
+            corner = np.clip(_near(kind, rng, box, pos, 1)[0] - side / 2, lo, hi - side)
+        else:
+            corner = rng.uniform(lo, hi - side)
         ax = [corner[i] + side[i] * (np.arange(g) + 0.5) / g for i in range(d)]
         mesh = np.stack(np.meshgrid(*ax, indexing="ij"), -1).reshape(-1, d)
         return mesh.astype(np.float32)
@@ -78,7 +104,7 @@ def _schedules() -> tuple:
     return tuple(np.random.default_rng(s) for s in seq.spawn(2))
 
 
-def open_requests(mix: dict, seconds: float, rng, box) -> Requests:
+def open_requests(mix: dict, seconds: float, rng, box, pos=None) -> Requests:
     count = int(round(mix["rate_per_s"] * seconds))
     kinds = _sizes(mix["requests"], count)
     sched = _schedules()[0]
@@ -95,7 +121,7 @@ def open_requests(mix: dict, seconds: float, rng, box) -> Requests:
             i = cycle.get(id(k), 0)
             cycle[id(k)] = i + 1
             rows = k["rows_min"] + i % (k["rows_max"] - k["rows_min"] + 1)
-        queries.append(_points(k, rng, box, rows))
+        queries.append(_points(k, rng, box, rows, pos))
     return Requests(due=due, queries=queries, kinds=[k["kind"] for k in kinds])
 
 
@@ -127,7 +153,9 @@ def reports(cfg: dict, net_pos: np.ndarray, fields, seconds: float, rng) -> Arri
     )
 
 
-def closed_request(mix: dict, rng, box) -> np.ndarray:
-    """One closed-loop request: ``rows_min``..``rows_max`` points."""
-    kind = {"kind": "points", "rows_min": mix["rows_min"], "rows_max": mix["rows_max"]}
-    return _points(kind, rng, box)
+def closed_request(mix: dict, rng, box, pos=None) -> np.ndarray:
+    """One closed-loop request: ``rows_min``..``rows_max`` points, near the
+    sensors where the mix says so."""
+    keys = ("rows_min", "rows_max", "near", "jitter")
+    kind = {"kind": "points", **{k: mix[k] for k in keys if k in mix}}
+    return _points(kind, rng, box, pos=pos)
