@@ -189,6 +189,14 @@ class TickReceipt(NamedTuple):
         }
 
 
+class _Inflight(NamedTuple):
+    """One dispatch launched by ``pump`` and not yet collected."""
+
+    batch: list  # the coalesced requests: (id, xq, t_submit)
+    rows: int  # real query rows (the output's padded columns are dropped)
+    out: jax.Array  # (B, bucket) answers, copying to the host
+
+
 _ecoef_jit = jax.jit(jax.named_scope("ecoef")(effective_coef))
 
 
@@ -287,6 +295,7 @@ class Daemon:
             "queue_wait_n": 0,
             "queue_wait_s_max": 0.0,
             "serve_traces": 0,  # jaxpr traces while launching dispatches
+            "pumps": 0,  # pumps that launched at least one dispatch
             "arrival_rows": 0,  # arrivals taken into absorb windows
             "arrival_padded_rows": 0,  # window rows after padding
         }
@@ -372,24 +381,53 @@ class Daemon:
         power-of-two bucket (``bucket_rows``), so ANY interleaving of
         request sizes lowers O(log max_batch_rows) distinct programs
         (tests/test_daemon.py property-tests this with the jit cache).
-        Every answer is read from one immutable snapshot — a concurrent
-        ``tick`` can flip the pointer mid-drain and in-flight dispatches
-        still see their snapshot's buffers.
+        Every answer of one pump is read from one immutable snapshot — a
+        concurrent ``tick`` can flip the pointer mid-drain and in-flight
+        dispatches still see their snapshot's buffers.
+
+        The drain runs in two phases, so the host never waits on one
+        dispatch before packing the next.  Launch: pack and launch every
+        queued dispatch, starting each output's copy to the host at once.
+        Collect: in launch order, wait for each output, fetch it and
+        answer its requests.  The answers are those of a one-at-a-time
+        drain bitwise (same programs, same operands); only where the host
+        waits changes.  The pipeline is as deep as the queue is when the
+        pump starts (in the cooperative loop nothing joins the queue
+        during a drain), so ``queue_rows`` bounds its device memory: the
+        in-flight outputs hold B values per padded row of the at most
+        ``queue_rows`` rows queued.
         """
         answers: list[QueryAnswer] = []
         with obs.span("daemon.pump") as sp:
-            rows = 0
+            t_pump = sp.stamp()
+            snap = self._snap
+            inflight = []
             while self._queries:
-                rows += self._dispatch(answers)
+                inflight.append(self._launch(snap))
+            for disp in inflight:
+                done_ns = self._collect(snap, disp, answers)
+            if inflight:
+                self._counters["pumps"] += 1
+                # One dispatch's service time is the drain's mean: in a
+                # launch-bound drain the later dispatches are done before
+                # the host collects them, so no per-dispatch stamp
+                # measures them.  It enters the EMA once per dispatch, as
+                # the serial drain's per-dispatch updates did.
+                n = len(inflight)
+                dt = (done_ns - t_pump) * 1e-9 / n
+                w = 0.8 ** n
+                self._ema_batch_s = (
+                    dt if self._ema_batch_s is None
+                    else w * self._ema_batch_s + (1.0 - w) * dt
+                )
             sp["requests"] = len(answers)
-            sp["rows"] = rows
+            sp["rows"] = sum(d.rows for d in inflight)
         return answers
 
-    def _dispatch(self, answers: list) -> int:
-        """One coalesced dispatch off the queue's front; appends its
-        answers and returns its rows."""
+    def _launch(self, snap: Snapshot) -> _Inflight:
+        """Pack one coalesced dispatch off the queue's front and launch it
+        without waiting for it."""
         c = self._counters
-        snap = self._snap  # one snapshot per dispatch
         with obs.span("serve.dispatch") as sp:
             t_disp = sp.stamp()
             with obs.span("serve.pack"):
@@ -412,7 +450,6 @@ class Daemon:
                         axis=0,
                     )
             with obs.span("serve.launch") as launch:
-                t_launch = launch.stamp()
                 traced = obs.traces()[0]
                 out = fusion.fuse(
                     snap.problem, snap.state, xq, "knn",
@@ -420,37 +457,13 @@ class Daemon:
                     plan=snap.plan, ecoef=snap.ecoef,
                     compute_dtype=self._compute_dtype, prune=snap.keep,
                 )
+                out.copy_to_host_async()
                 traced = obs.traces()[0] - traced
                 launch["traces"] = traced
-            with obs.span("serve.wait"):
-                out.block_until_ready()
-            with obs.span("serve.fetch") as fetch:
-                done_ns = fetch.stamp()
-                vals = np.asarray(out)
-                fetch["bytes"] = vals.nbytes
-            with obs.span("serve.answer"):
-                done = done_ns * 1e-9
-                off = 0
-                for qid, grid, t_submit in batch:
-                    q = grid.shape[0]
-                    answers.append(QueryAnswer(
-                        id=qid,
-                        values=vals[:, off:off + q],
-                        version=snap.version,
-                        degraded=self.degraded,
-                        latency_s=done - t_submit,
-                    ))
-                    off += q
             sp["rows"] = rows
             sp["bucket"] = q_pad
             sp["requests"] = len(batch)
             sp["version"] = snap.version
-        dt = (done_ns - t_launch) * 1e-9
-        self._ema_batch_s = (
-            dt if self._ema_batch_s is None
-            else 0.8 * self._ema_batch_s + 0.2 * dt
-        )
-        self.served += len(batch)
         key = str(q_pad)
         c["dispatches"][key] = c["dispatches"].get(key, 0) + 1
         c["rows"] += rows
@@ -462,7 +475,34 @@ class Daemon:
             c["queue_wait_s_max"] = max(c["queue_wait_s_max"], wait)
         c["queue_wait_n"] += len(batch)
         c["serve_traces"] += traced
-        return rows
+        return _Inflight(batch, rows, out)
+
+    def _collect(self, snap: Snapshot, disp: _Inflight,
+                 answers: list) -> int:
+        """Wait for one launched dispatch, append its answers, and return
+        its completion stamp (ns)."""
+        with obs.span("serve.collect"):
+            with obs.span("serve.wait"):
+                disp.out.block_until_ready()
+            with obs.span("serve.fetch") as fetch:
+                done_ns = fetch.stamp()
+                vals = np.asarray(disp.out)
+                fetch["bytes"] = vals.nbytes
+            with obs.span("serve.answer"):
+                done = done_ns * 1e-9
+                off = 0
+                for qid, grid, t_submit in disp.batch:
+                    q = grid.shape[0]
+                    answers.append(QueryAnswer(
+                        id=qid,
+                        values=vals[:, off:off + q],
+                        version=snap.version,
+                        degraded=self.degraded,
+                        latency_s=done - t_submit,
+                    ))
+                    off += q
+        self.served += len(disp.batch)
+        return done_ns
 
     # -- trainer-side inputs -----------------------------------------------
 
@@ -669,7 +709,10 @@ class Daemon:
         ``padded_rows`` (bucket fill = their ratio), the submit -> dispatch
         queue wait (``queue_wait_s_sum`` / ``_n`` / ``_max``), the jaxpr
         traces the dispatches caused (``serve_traces``; a warmed path that
-        keeps tracing retraces per call), and ``arrival_rows`` /
+        keeps tracing retraces per call), the non-empty ``pumps`` (every
+        dispatch of a pump but its first is launched while an earlier one
+        is in flight, so 1 - pumps / Σ ``dispatches`` is the share the
+        pipeline overlapped), and ``arrival_rows`` /
         ``arrival_padded_rows`` over the absorb windows.
         """
         t = self.last_tick

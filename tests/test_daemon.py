@@ -150,6 +150,122 @@ def test_warm_pump_traces_and_compiles_nothing():
     assert counters["dispatches"] == {str(b): 2 for b in buckets}
 
 
+# Runs of 1-8-row points between full 256-row tiles: eight dispatches
+# under the default 256-row cap (points, tile, points, tile, ...).
+_MIX = [3, 8, 1, 5, 2, 7] * 4 + [256] + [4, 6, 1] * 9 + [256] + [2] * 40 \
+    + [256] + [8, 8, 3] + [256]
+
+
+def _serial_answers(snap, grids, ids, max_rows=256):
+    """The one-at-a-time drain: coalesce front-to-back under the cap, pad
+    to the bucket, answer each batch through ``fusion.fuse`` on ``snap``
+    and wait for it before packing the next.  Returns the (id, values)
+    answers and the number of dispatches."""
+    out, i, dispatches = [], 0, 0
+    while i < len(grids):
+        j, rows = i + 1, grids[i].shape[0]
+        while j < len(grids) and rows + grids[j].shape[0] <= max_rows:
+            rows += grids[j].shape[0]
+            j += 1
+        xq = np.concatenate(grids[i:j])
+        xq = np.concatenate([xq, np.repeat(xq[-1:], bucket_rows(rows) - rows, 0)])
+        vals = np.asarray(fusion.fuse(
+            snap.problem, snap.state, xq, "knn", k=3, engine="plan",
+            plan=snap.plan, ecoef=snap.ecoef, prune=snap.keep,
+        ))
+        off = 0
+        for g, qid in zip(grids[i:j], ids[i:j]):
+            out.append((qid, vals[:, off:off + g.shape[0]]))
+            off += g.shape[0]
+        i, dispatches = j, dispatches + 1
+    return out, dispatches
+
+
+def test_pipelined_pump_answers_bitwise_like_the_serial_drain():
+    """A pump launches every queued dispatch before collecting any; its
+    answers equal the serial drain's bitwise (values, id order, version,
+    degraded), before and after a publishing tick, and the counters say
+    that the pipeline overlapped every dispatch of a pump but its first."""
+    prob, state, pos, _ = _build(seed=11)
+    cfg = DaemonConfig(k=3, arrival_rows=8, queue_rows=2048)
+    d = Daemon(prob, state, config=cfg)
+    rng = np.random.default_rng(11)
+
+    def pump_and_check(version):
+        grids = [rng.uniform(-0.9, 0.9, size=(q, 1)).astype(np.float32)
+                 for q in _MIX]
+        tickets = [d.submit(g) for g in grids]
+        assert all(t.admitted for t in tickets)
+        ids = [t.id for t in tickets]
+        c0 = d.health()["counters"]
+        answers = d.pump()
+        want, n_disp = _serial_answers(d.snapshot, grids, ids)
+        assert n_disp >= 6
+        assert [a.id for a in answers] == ids
+        for a, (qid, vals) in zip(answers, want):
+            assert a.id == qid
+            np.testing.assert_array_equal(a.values, vals)
+            assert a.version == version and a.degraded is False
+        c = d.health()["counters"]
+        assert sum(c["dispatches"].values()) - sum(
+            c0["dispatches"].values()) == n_disp
+        assert c["pumps"] - c0["pumps"] == 1  # overlapped: n_disp - 1
+        return c
+
+    pump_and_check(0)
+    ss = rng.integers(0, prob.n, size=5)
+    d.offer_arrivals(rng.integers(0, 3, size=5), ss,
+                     (pos[ss] + 0.02).astype(np.float32),
+                     rng.normal(size=5).astype(np.float32))
+    assert d.tick().published
+    c = pump_and_check(1)  # the new snapshot
+    d.submit(np.zeros((5, 1), np.float32))
+    (one,) = d.pump()  # one dispatch: no pipeline
+    assert one.version == 1
+    c1 = d.health()["counters"]
+    assert c1["pumps"] == c["pumps"] + 1
+    assert c1["dispatches"]["8"] == c["dispatches"].get("8", 0) + 1
+    assert d.pump() == []  # an empty pump is no pump
+    assert d.health()["counters"]["pumps"] == c1["pumps"]
+
+
+@pytest.mark.parametrize("launch_bound", [False, True],
+                         ids=["device_bound", "launch_bound"])
+def test_admission_estimate_stays_per_dispatch(launch_bound, monkeypatch):
+    """The EMA dispatch time is the drain's mean service time of one
+    dispatch, whatever its place in the pipeline: after a first pump of
+    N >= 6 dispatches it lies between half and all of (pump span) / N.
+    The launch-bound case finishes each dispatch inside its launch, as a
+    chip does whose host launches slower than it computes; the later
+    dispatches are then done before their collect, so a stamp per
+    dispatch (completion - launch, or completion - previous completion)
+    misreads them."""
+    from repro import obs
+
+    if launch_bound:
+        fuse = fusion.fuse
+        monkeypatch.setattr(
+            fusion, "fuse", lambda *a, **kw: fuse(*a, **kw).block_until_ready())
+    prob, state, _, _ = _fix()
+    d = Daemon(prob, state, config=DaemonConfig(k=3, queue_rows=2048))
+    rng = np.random.default_rng(12)
+    for q in _MIX:
+        assert d.submit(
+            rng.uniform(-0.9, 0.9, size=(q, 1)).astype(np.float32)).admitted
+    obs.enable()
+    try:
+        d.pump()
+        spans, _ = obs.drain()
+    finally:
+        obs.disable()
+        obs.drain()
+    (pump,) = [s for s in spans if s[0] == "daemon.pump"]
+    n = sum(s[0] == "serve.dispatch" for s in spans)
+    assert n >= 6
+    per_dispatch = (pump[2] - pump[1]) * 1e-9 / n
+    assert 0.5 * per_dispatch <= d._ema_batch_s <= per_dispatch
+
+
 def test_pad_arrivals_is_bitwise_noop():
     """Absorbing a window padded with sentinel-row arrivals must equal the
     unpadded absorb bitwise — problem, state, and real-row receipt flags."""

@@ -141,20 +141,23 @@ def test_pump_span_tree(trained):
     (pump,) = [i for i, s in enumerate(spans) if s[0] == "daemon.pump"]
     assert spans[pump][3] == -1
     assert spans[pump][4] == {"requests": len(SIZES), "rows": sum(SIZES)}
-    dispatches = _children(spans, pump)
-    assert [spans[i][0] for i in dispatches] == ["serve.dispatch"] * 2
+    kids = _children(spans, pump)
+    assert [spans[i][0] for i in kids] == (
+        ["serve.dispatch"] * 2 + ["serve.collect"] * 2)
+    dispatches, collects = kids[:2], kids[2:]
     attrs = [spans[i][4] for i in dispatches]
     assert [a["rows"] for a in attrs] == [60, 30]
     assert [a["bucket"] for a in attrs] == [64, 32]
     assert sum(a["requests"] for a in attrs) == len(SIZES)
     assert {a["version"] for a in attrs} == {version}
-    for i in dispatches:
-        kids = _children(spans, i)
-        assert [spans[j][0] for j in kids] == [
-            "serve.pack", "serve.launch", "serve.wait", "serve.fetch",
-            "serve.answer"]
-        assert "traces" in spans[kids[1]][4]
-        assert spans[kids[3]][4]["bytes"] == 3 * spans[i][4]["bucket"] * 4
+    for i, j in zip(dispatches, collects):
+        launched = _children(spans, i)
+        assert [spans[m][0] for m in launched] == ["serve.pack", "serve.launch"]
+        assert "traces" in spans[launched[1]][4]
+        collected = _children(spans, j)
+        assert [spans[m][0] for m in collected] == [
+            "serve.wait", "serve.fetch", "serve.answer"]
+        assert spans[collected[1]][4]["bytes"] == 3 * spans[i][4]["bucket"] * 4
     for name, t0, t1, parent, _ in spans:
         assert t0 <= t1, name
         if parent >= 0:
